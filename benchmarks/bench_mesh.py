@@ -31,7 +31,9 @@ Usage: python benchmarks/bench_mesh.py [--smoke] [--json-stdout]
 Runnable standalone (self-locates ``src/``, forces 8 host devices
 before the first jax import) or via benchmarks/run.py --json, which
 re-execs this file in a child interpreter when jax is already
-initialised single-device.
+initialised single-device on the CPU. On an accelerator it runs in
+process on the real devices, and fails with a message when there are
+fewer than 4.
 """
 from __future__ import annotations
 
@@ -225,9 +227,23 @@ def sweep() -> list:
 # ---------------------------------------------------------------------------
 
 
+def _needs_respawn() -> bool:
+    """True when this process has too few devices for a 4-shard mesh and
+    a child with forced host devices can supply them — on the CPU only.
+    On an accelerator this process holds the chip and a child could not
+    take it, so too few devices there is an error."""
+    devs = jax.devices()
+    if len(devs) >= 4:
+        return False
+    if devs[0].platform != "cpu":
+        raise SystemExit(
+            f"bench_mesh needs 4 devices; {devs[0].platform} shows {len(devs)}")
+    return True
+
+
 def _respawn(args: list) -> subprocess.CompletedProcess:
-    """Re-exec this file in a child interpreter with forced devices (jax
-    in this process is already initialised with too few)."""
+    """Re-exec this file in a child interpreter with forced host devices
+    (jax in this process is already initialised on too few CPU ones)."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={DEVICE_COUNT}"
     return subprocess.run([sys.executable, __file__, *args],
@@ -236,7 +252,7 @@ def _respawn(args: list) -> subprocess.CompletedProcess:
 
 def smoke() -> int:
     """CI mode: bit-equality + residency acceptance bars."""
-    if len(jax.devices()) < 4:
+    if _needs_respawn():
         out = _respawn(["--smoke"])
         sys.stdout.write(out.stdout)
         sys.stderr.write(out.stderr)
@@ -257,7 +273,7 @@ def json_report() -> dict:
     When the hosting process already initialised jax single-device (the
     run.py case), computes in a re-exec'd child and parses its stdout.
     """
-    if len(jax.devices()) < 4:
+    if _needs_respawn():
         out = _respawn(["--json-stdout"])
         if out.returncode != 0:
             raise RuntimeError(f"bench_mesh child failed:\n{out.stderr}")

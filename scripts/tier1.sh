@@ -16,6 +16,9 @@
 set -euo pipefail
 cd "$(dirname "$0")/.."
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
+# tier-1 is the CPU lane (interpret-mode kernels, jnp oracles); the
+# chip's own check is chip_smoke.py
+export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
 python scripts/check_docs.py
 python benchmarks/bench_aggregation.py --smoke
 python benchmarks/bench_retrieval.py --smoke

@@ -72,7 +72,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-import os
 from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
@@ -85,6 +84,7 @@ from repro.core import channel as chan
 from repro.core import packing, quant, wire
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
+from repro.kernels.ota_fused import INT4_GROUP
 
 Pytree = Any
 
@@ -114,13 +114,9 @@ def _use_kernel_default() -> bool:
     sum-of-squares accumulation is a TPU pattern (GPU grids run blocks in
     parallel). On CPU, interpret-mode Pallas runs the kernel body per grid
     step under the interpreter — orders of magnitude slower than the
-    XLA-fused jnp formulation with identical numerics.
-    REPRO_OTA_FORCE_KERNEL=1 forces the kernel anyway (interpret mode on
-    CPU), e.g. for equivalence testing.
+    XLA-fused jnp formulation with the same numerics. Tests that check
+    the kernel on CPU pass ``use_kernel=True`` explicitly.
     """
-    forced = os.environ.get("REPRO_OTA_FORCE_KERNEL")
-    if forced is not None:
-        return forced.strip().lower() not in ("0", "false", "no", "off", "")
     return jax.devices()[0].platform == "tpu"
 
 
@@ -283,11 +279,14 @@ _fold_ref_jit = jax.jit(kref.ota_fold_ref, static_argnames=("qblock", "packed4")
 def _shard_chunk(M: int, n_shards: int, kinds) -> int:
     """Per-shard column-chunk width for the mesh-sharded fold
     (DESIGN.md §15): ceil(M / n_shards) rounded up so every blockwise
-    scale group (qblock columns) and every int4 nibble pair stays whole
-    inside one shard's chunk — each shard's local block-id gather and
-    nibble unpack are then literally the unsharded ones."""
+    scale group (qblock columns) and every planar int4 group
+    (``INT4_GROUP`` symbols) stays whole inside one shard's chunk — each
+    shard's local block-id gather and nibble unpack are then literally
+    the unsharded ones."""
     align = 2
-    for _, qblock in kinds:
+    for kind, qblock in kinds:
+        if kind == "int4":
+            align = math.lcm(align, INT4_GROUP)
         if qblock > 0:
             align = math.lcm(align, int(qblock))
     mc = -(-M // n_shards)
@@ -328,8 +327,6 @@ def _sharded_group_program(
     class, scale placement, acc/gains presence, backend), so varying
     cohorts reuse compiled programs across rounds exactly like the
     unsharded pieces."""
-    from jax.experimental.shard_map import shard_map
-
     P = jax.sharding.PartitionSpec
     packed4 = kind == "int4"
 
@@ -352,15 +349,15 @@ def _sharded_group_program(
     ]
     if has_gains:
         in_specs.append(P())
-    # check_rep=False: jax 0.4.x has no replication rule for pallas_call,
-    # so the kernel path would otherwise refuse to trace under shard_map
+    # check_vma=False: pallas_call has no varying-manual-axes rule, so
+    # the kernel path would otherwise refuse to trace under shard_map
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             body,
             mesh=mesh,
             in_specs=tuple(in_specs),
             out_specs=P("data"),
-            check_rep=False,
+            check_vma=False,
         )
     )
 
@@ -398,6 +395,7 @@ def _fold_groups_sharded(
             x, jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec(*spec))
         )
 
+    path = kops.kernel_path(use_kernel)
     with obs.span("shard_fold", shards=n_shards, groups=len(kinds), chunk=mc):
         running = acc
         if running is not None:
@@ -407,7 +405,7 @@ def _fold_groups_sharded(
         off = 0
         for (kind, qblock), data, scale in zip(kinds, datas, scales):
             kg = scale.shape[0]
-            obs.metrics.inc("ota.rows", kg, kind=kind)
+            obs.metrics.inc("ota.rows", kg, kind=kind, path=path)
             wseg = wg[off : off + kg]
             gseg = None if gains is None else gains[off : off + kg]
             off += kg
@@ -461,9 +459,10 @@ def _fold_groups(
     by construction.
 
     Telemetry (DESIGN.md §14): the whole fold runs under one ``fold``
-    span, and each storage group bumps the per-storage-class row
-    counter ``ota.rows{kind=...}`` — the observation side only; the
-    folded values are untouched either way.
+    span, and each storage group bumps the row counter
+    ``ota.rows{kind=...,path=...}`` — storage class and data-plane path
+    (``kernels.ops.kernel_path``: kernel / interpret / ref); the
+    observation side only, the folded values are untouched either way.
 
     ``mesh``: optional 1-D device mesh with a ``data`` axis
     (``launch.mesh.make_data_mesh``) — routes to the column-sharded
@@ -475,11 +474,12 @@ def _fold_groups(
             acc, kinds, datas, scales, wg, gains=gains, mesh=mesh,
             use_kernel=use_kernel,
         )
+    path = kops.kernel_path(use_kernel)
     with obs.span("fold", groups=len(kinds)):
         off = 0
         for (kind, qblock), data, scale in zip(kinds, datas, scales):
             kg = scale.shape[0]
-            obs.metrics.inc("ota.rows", kg, kind=kind)
+            obs.metrics.inc("ota.rows", kg, kind=kind, path=path)
             wseg = jax.lax.slice_in_dim(wg, off, off + kg)
             gseg = (
                 None if gains is None else jax.lax.slice_in_dim(gains, off, off + kg)

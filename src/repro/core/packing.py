@@ -30,6 +30,8 @@ from typing import Any, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.ota_fused import INT4_GROUP
+
 Pytree = Any
 
 # Matches kernels.ota_fused.BLOCK_COLS: packed vectors tile evenly into the
@@ -119,8 +121,8 @@ def row_wire_bytes(bits: int, padded_size: int, block: int = 0) -> int:
     if kind == "float32":
         return 4 * padded_size
     nscales = n_scale_blocks(block, padded_size)
-    if kind == "int4":  # two symbols per byte, odd length rounds up
-        return (padded_size + 1) // 2 + 4 * nscales
+    if kind == "int4":  # two symbols per byte, in whole 256-symbol groups
+        return -(-padded_size // INT4_GROUP) * INT4_GROUP // 2 + 4 * nscales
     per = {"int8": 1, "int16": 2, "int32": 4}[kind]
     return per * padded_size + 4 * nscales
 
@@ -130,8 +132,9 @@ class PackedRow:
     """One client's uplink in wire form: quantized symbols + analog grid.
 
     data: (padded_size//2,) uint8 for a 4-bit client (two symbols per
-    byte, ``kernels.ops.pack_int4_rows``), (padded_size,) int8/int16/
-    int32 for 5..8 / 9..16 / 17..31 bits, or the (padded_size,) f32 row
+    byte in planar 256-symbol groups, ``kernels.ops.pack_int4_rows``),
+    (padded_size,) int8/int16/int32 for 5..8 / 9..16 / 17..31 bits,
+    or the (padded_size,) f32 row
     for an unquantized client (bits >= 32, or <= 1 where the symmetric
     grid is empty). scale is the f32 analog grid step: the () per-update
     scalar of the PR-2 format (the ``qblock`` = 0 degenerate case — old

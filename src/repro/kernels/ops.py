@@ -24,6 +24,21 @@ def _on_cpu() -> bool:
     return jax.devices()[0].platform == "cpu"
 
 
+def interpret_mode() -> bool:
+    """True off-TPU: the Pallas data-plane kernels then run under the
+    interpreter (a correctness tool for tests), never compiled."""
+    return jax.devices()[0].platform != "tpu"
+
+
+def kernel_path(use_kernel: bool) -> str:
+    """Path label a data-plane call reports: "kernel" (compiled on the
+    TPU), "interpret" (the kernel body under the interpreter) or "ref"
+    (the XLA-compiled jnp reference)."""
+    if not use_kernel:
+        return "ref"
+    return "interpret" if interpret_mode() else "kernel"
+
+
 def _pad_to(x: jnp.ndarray, m: int, axis: int = 0) -> Tuple[jnp.ndarray, int]:
     n = x.shape[axis]
     pad = (-n) % m
@@ -94,7 +109,7 @@ def ota_quantize_superpose(
     sumsq accumulation relies on TPU sequential-grid semantics and would
     race under a parallel (GPU) grid.
     """
-    interpret = jax.devices()[0].platform != "tpu"
+    interpret = interpret_mode()
     M = x.shape[1]
     xp, _ = _pad_to(x, _otaf.BLOCK_COLS, axis=1)
     acc, ss = _otaf.ota_fused_2d(
@@ -116,7 +131,7 @@ def ota_dequant_superpose(
     """Receiver half of the packed uplink: dequant + weighted superpose.
 
     q: (K, M) int8/int16/f32 pre-quantized client symbols, or (K, M//2)
-    uint8 row-major int4 nibbles when ``packed4`` (``pack_int4_rows``).
+    uint8 planar int4 nibbles when ``packed4`` (``pack_int4_rows``).
     scale: (K,) per-update scales or the (K, n_blocks) blockwise scale
     matrix (``qblock`` symbols per scale; 0 = per-update). w: (K,).
     ``gains``: optional (K,) per-row effective channel gain (fading +
@@ -130,7 +145,7 @@ def ota_dequant_superpose(
     ``ref.ota_packed_ref``. Interpret mode off-TPU (CPU correctness tool;
     the jnp oracle is the CPU perf path, as with ota_quantize_superpose).
     """
-    interpret = jax.devices()[0].platform != "tpu"
+    interpret = interpret_mode()
     bc = _otaf.BLOCK_COLS // 2 if packed4 else _otaf.BLOCK_COLS
     M = 2 * q.shape[1] if packed4 else q.shape[1]
     qp, _ = _pad_to(q, bc, axis=1)
@@ -162,8 +177,8 @@ def topk_cosine(
     Returns (scores (Q, k) f32, idx (Q, k) int32) under the engine's tie
     contract (descending score, ties by ascending index). With
     ``use_kernel`` the Pallas kernel runs (interpret mode off-TPU);
-    otherwise the bit-equal jnp oracle ``ref.topk_similarity_ref`` — the
-    CPU perf path, as with the OTA kernels.
+    otherwise the jnp oracle ``ref.topk_similarity_ref`` (bit-equal in
+    interpret mode) — the CPU perf path, as with the OTA kernels.
     """
     from repro.kernels import ref as _ref
     from repro.kernels import topk_similarity as _tk
@@ -173,8 +188,8 @@ def topk_cosine(
     Qp = -(-Q // 8) * 8  # f32 sublane multiple
     qp = jnp.pad(qm, ((0, Qp - Q), (0, 0))) if Qp != Q else qm
     if use_kernel:
-        interpret = jax.devices()[0].platform != "tpu"
-        s, i = _tk.topk_similarity_2d(qp, recs, scales, n, interpret=interpret)
+        interpret = interpret_mode()
+        s, i = _tk.topk_similarity_2d(qp, recs, scales, n, k=k, interpret=interpret)
     else:
         s, i = _ref.topk_similarity_ref(qp, recs, scales, n)
     return s[:Q, :k], i[:Q, :k]
@@ -209,7 +224,6 @@ def topk_cosine_sharded(
     ``topk_cosine`` — scores and indices. k <= TOPK_LANES guarantees
     any global top-k member survives its shard's lane budget.
     """
-    from jax.experimental.shard_map import shard_map
     from repro.kernels import ref as _ref
     from repro.kernels import topk_similarity as _tk
 
@@ -222,14 +236,15 @@ def topk_cosine_sharded(
     assert 0 < k <= _tk.TOPK_LANES, k
     Qp = -(-Q // 8) * 8  # f32 sublane multiple
     qp = jnp.pad(qm, ((0, Qp - Q), (0, 0))) if Qp != Q else qm
-    interpret = jax.devices()[0].platform != "tpu"
+    interpret = interpret_mode()
 
     def _local_topk(qloc, rloc, sloc, nloc):
         lo = jax.lax.axis_index("data") * rows
         n_local = jnp.clip(nloc - lo, 0, rows)
         if use_kernel:
-            s, i = _tk.topk_similarity_2d(qloc, rloc, sloc, n_local,
-                                          interpret=interpret)
+            s, i = _tk.topk_similarity_2d(
+                qloc, rloc, sloc, n_local, k=k, interpret=interpret
+            )
         else:
             s, i = _ref.topk_similarity_ref(qloc, rloc, sloc, n_local)
         return s[None], (i + lo)[None]
@@ -242,8 +257,13 @@ def topk_cosine_sharded(
         body = _local_topk
         in_specs = (P(), P("data"), P("data"), P())
         args = (qp, recs, scales, n)
-    s, i = shard_map(
-        body, mesh=mesh, in_specs=in_specs, out_specs=(P("data"), P("data"))
+    # check_vma=False: pallas_call has no varying-manual-axes rule
+    s, i = jax.shard_map(
+        body,
+        mesh=mesh,
+        in_specs=in_specs,
+        out_specs=(P("data"), P("data")),
+        check_vma=False,
     )(*args)
     # (shards, Qp, LANES) candidates -> flatten the shard axis in index
     # order: every tied set is then positionally ascending-index, and
@@ -278,10 +298,10 @@ def ota_fold_packed(
     legacy program). Returns acc + the batch's weighted dequantized
     superposition, so a round becomes
     fold(fold(fold(state, batch0), batch1), ...) instead of one (K, M)
-    barrier. Oracle: ``ref.ota_fold_ref`` (bit-equal; the jnp path is
-    the CPU perf path, as with the other OTA kernels).
+    barrier. Oracle: ``ref.ota_fold_ref`` (within ``ref.ota_fold_bound``;
+    the jnp path is the CPU perf path, as with the other OTA kernels).
     """
-    interpret = jax.devices()[0].platform != "tpu"
+    interpret = interpret_mode()
     bc = _otaf.BLOCK_COLS // 2 if packed4 else _otaf.BLOCK_COLS
     M = 2 * q.shape[1] if packed4 else q.shape[1]
     qp, _ = _pad_to(q, bc, axis=1)
@@ -388,34 +408,38 @@ def unpack_int4(packed: jnp.ndarray) -> jnp.ndarray:
 
 
 def pack_int4_rows(q: jnp.ndarray) -> jnp.ndarray:
-    """Row-major int4 pack: (..., M) int values in [-8, 7] -> (..., ceil(M/2))
-    uint8, adjacent *elements* sharing a byte (low nibble = even index).
+    """Planar int4 pack: (..., M) int values in [-8, 7] -> (..., P) uint8,
+    P = 128 * ceil(M / 256).
 
     The uplink wire variant of ``pack_int4`` (which pairs adjacent *rows*
     for the weight layout): a client's flat update row stays a row, at
-    half the bytes. Odd M is zero-padded by one symbol; ``unpack_int4_rows``
-    takes the logical length to trim it back.
+    half the bytes. Symbols go in groups of ``ota_fused.INT4_GROUP`` =
+    256: in group g, byte j holds symbol 256 g + j in its low nibble and
+    symbol 256 g + 128 + j in its high nibble, so the kernel unpacks a
+    tile by concatenating lane-aligned slices (``ota_fused._unpack_tile``).
+    A row that is not a whole number of groups is zero-padded;
+    ``unpack_int4_rows`` takes the logical length to trim it back.
     """
-    M = q.shape[-1]
-    if M % 2:
-        pad = [(0, 0)] * (q.ndim - 1) + [(0, 1)]
-        q = jnp.pad(q, pad)
-    lo = q[..., 0::2].astype(jnp.uint8) & 0x0F
-    hi = q[..., 1::2].astype(jnp.uint8) & 0x0F
-    return (lo | (hi << 4)).astype(jnp.uint8)
+    group = _otaf.INT4_GROUP
+    pad = (-q.shape[-1]) % group
+    if pad:
+        q = jnp.pad(q, [(0, 0)] * (q.ndim - 1) + [(0, pad)])
+    g = q.reshape(*q.shape[:-1], -1, 2, group // 2).astype(jnp.uint8) & 0x0F
+    out = g[..., 0, :] | (g[..., 1, :] << 4)
+    return out.reshape(*q.shape[:-1], -1).astype(jnp.uint8)
 
 
 def unpack_int4_rows(packed: jnp.ndarray, n: Optional[int] = None) -> jnp.ndarray:
-    """Inverse of ``pack_int4_rows``: (..., P) uint8 -> (..., n) int8.
+    """Inverse of ``pack_int4_rows``: (..., P) uint8 -> (..., n) int32.
 
-    ``n`` trims the trailing pad symbol of an odd-length row (defaults to
-    2P). Same nibble math as the in-kernel unpack
-    (``ota_fused._unpack_nibbles``) — the bit-equality contract between
-    the packed aggregation kernel and its jnp oracle rides on that.
+    ``n`` trims the zero padding of a row that is not a whole number of
+    groups (defaults to 2P). Same nibble math as the in-kernel unpack
+    (``ota_fused.nibbles``).
     """
-    from repro.kernels.ota_fused import _unpack_nibbles
-
-    out = _unpack_nibbles(packed)
+    half = _otaf.INT4_GROUP // 2
+    lo, hi = _otaf.nibbles(packed.reshape(*packed.shape[:-1], -1, half))
+    out = jnp.concatenate([lo, hi], axis=-1)
+    out = out.reshape(*packed.shape[:-1], 2 * packed.shape[-1])
     return out if n is None else out[..., :n]
 
 

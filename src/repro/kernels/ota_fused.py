@@ -50,6 +50,7 @@ from jax.experimental import pallas as pl
 
 BLOCK_COLS = 2048
 LANES = 128
+INT4_GROUP = 2 * LANES  # symbols per planar int4 wire group (``_unpack_tile``)
 
 _GOLDEN = 0x9E3779B9  # Weyl increment decorrelating client rows
 
@@ -71,7 +72,9 @@ def sr_dither(seed, rows, pos) -> jnp.ndarray:
     h = h ^ (h >> jnp.uint32(13))
     h = h * jnp.uint32(0xC2B2AE35)
     h = h ^ (h >> jnp.uint32(16))
-    return (h >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
+    # via int32: exact below 2^24, and Mosaic has no uint32 -> f32 cast
+    u24 = (h >> jnp.uint32(8)).astype(jnp.int32)
+    return u24.astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
 
 
 def _fused_kernel(seed_ref, scale_ref, qmax_ref, w_ref, x_ref, o_ref, ss_ref):
@@ -94,54 +97,88 @@ def _fused_kernel(seed_ref, scale_ref, qmax_ref, w_ref, x_ref, o_ref, ss_ref):
     q = floor + (u < (scaled - floor)).astype(jnp.float32)
     q = jnp.clip(q, -qmax, qmax)
     dq = jnp.where(qmax > 0, q * scale, x)
-    acc = jnp.sum(dq * w, axis=0)               # (B,)
+    acc = jnp.sum(dq * w, axis=0, keepdims=True)  # (1, B)
     o_ref[...] = acc.reshape(o_ref.shape)
 
     @pl.when(i == 0)
     def _init():
-        ss_ref[0, 0] = 0.0
+        ss_ref[...] = jnp.zeros(ss_ref.shape, jnp.float32)
 
-    ss_ref[0, 0] += jnp.sum(acc * acc)
+    # (1, 1) vector accumulate: Mosaic cannot store a scalar to VMEM
+    ss_ref[...] += jnp.sum(acc * acc, axis=1, keepdims=True)
 
 
-def _unpack_nibbles(p: jnp.ndarray) -> jnp.ndarray:
-    """(..., N) uint8 -> (..., 2N) int8: low nibble first, sign-extended.
+def nibbles(p: jnp.ndarray):
+    """uint8 bytes -> (low, high) nibbles as sign-extended int32 symbols.
 
-    The in-kernel half of the row-major int4 wire format
-    (``kernels.ops.pack_int4_rows``); kept here so the Pallas kernel body
-    and the jnp oracle run the exact same ops (bit-equality contract).
+    Widened to int32 before any bit op: the TPU vector unit has no 8-bit
+    arithmetic. Shared by the in-kernel unpack and the host-side
+    ``kernels.ops.unpack_int4_rows``, so both run the same ops.
     """
-    lo = (p & jnp.uint8(0x0F)).astype(jnp.int8)
-    hi = ((p >> jnp.uint8(4)) & jnp.uint8(0x0F)).astype(jnp.int8)
-    lo = jnp.where(lo >= 8, lo - 16, lo)
-    hi = jnp.where(hi >= 8, hi - 16, hi)
-    return jnp.stack([lo, hi], axis=-1).reshape(*p.shape[:-1], 2 * p.shape[-1])
+    x = p.astype(jnp.int32)
+    lo = x & 0x0F
+    hi = (x >> 4) & 0x0F
+    return jnp.where(lo >= 8, lo - 16, lo), jnp.where(hi >= 8, hi - 16, hi)
 
 
-def _tile_scale_cols(scale_ref, i, K, B, qblock, aligned):
+def _unpack_tile(p: jnp.ndarray) -> jnp.ndarray:
+    """(K, N) uint8 wire tile -> (K, 2N) int32 symbols, N % LANES == 0.
+
+    The in-kernel half of the planar int4 wire format
+    (``kernels.ops.pack_int4_rows``): each 128-byte lane group holds a
+    256-symbol group, symbol j in the low nibble of byte j and symbol
+    128 + j in its high nibble. Unpacking is then a concatenation of
+    lane-aligned slices — no interleaving reshape, which Mosaic cannot
+    lower.
+    """
+    lo, hi = nibbles(p)
+    parts = []
+    for g in range(p.shape[1] // LANES):
+        sl = slice(g * LANES, (g + 1) * LANES)
+        parts += [lo[:, sl], hi[:, sl]]
+    return jnp.concatenate(parts, axis=1)
+
+
+def repeat_lanes(s, first, count, width):
+    """Columns ``first`` .. ``first + count - 1`` of ``s`` (R, L), each
+    repeated ``width`` times along lanes -> (R, count * width).
+
+    What ``jnp.repeat`` of a lane slice gives, built from one-hot lane
+    sums (one nonzero term each: exact) and broadcasts, which Mosaic
+    lowers where it refuses repeat's reshape and a dynamic lane slice.
+    ``first`` may be traced.
+    """
+    lane = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    cols = [
+        jnp.sum(jnp.where(lane == first + b, s, 0.0), axis=1, keepdims=True)
+        for b in range(count)
+    ]
+    return jnp.concatenate([jnp.broadcast_to(c, (s.shape[0], width)) for c in cols], 1)
+
+
+def _tile_scale_cols(scale_ref, i, K, B, qblock, mode):
     """Per-column dequant scales for grid step ``i``'s (K, B) symbol tile.
 
-    ``aligned`` (qblock divides the logical tile width — true for every
-    power-of-two block <= BLOCK_COLS, incl. the 256 default): scale_ref
-    is the (K, B // qblock) slice of the scale matrix this tile owns,
-    streamed per grid step by its BlockSpec exactly like the symbol
-    tile, and expanded by a static repeat — VMEM stays O(K * B/qblock)
-    no matter how large M grows (at M = 16M the full matrix would be
-    K * 256 KB, which does NOT fit VMEM resident). Unaligned block
-    sizes fall back to the whole (K, n_blocks) matrix resident + a
-    positional gather (fine for the small/ragged cases that produce
-    them). ``qblock`` = 0 means one per-update scale (n_blocks = 1):
-    the (K, 1) column broadcasts with no gather — the PR-2 path,
-    bit-exact. Positions past the last block (lane padding) clip to it
-    in the gather and read padded 1.0 scales in the aligned path;
-    padding symbols are exact zeros so the value there is irrelevant.
+    ``mode`` (from ``_packed_specs``):
+
+    - ``"row"``: one per-update scale; the (K, 1) column broadcasts.
+    - ``"lanes"`` (qblock a multiple of 128 dividing B — incl. the 256
+      default): scale_ref is the (K, 128) block of the scale matrix
+      covering 128 quantization blocks, streamed by its BlockSpec and
+      shared by ``128 // (B // qblock)`` consecutive grid steps, so
+      VMEM stays O(K * B) for any M. This step's B // qblock columns
+      are repeated over their qblock lanes (``repeat_lanes``).
+    - ``"gather"`` (any other qblock; interpret mode only, see
+      ``_packed_specs``): the whole (K, n_blocks) matrix is resident and
+      indexed per column. Positions past the last block clip to it;
+      padding symbols are exact zeros, so the value there never shows.
     """
     scales = scale_ref[...].astype(jnp.float32)
-    if qblock <= 0 or (not aligned and scales.shape[1] == 1):
-        return scales  # (K, 1) broadcast — per-row degenerate case
-    if aligned:
-        return jnp.repeat(scales, qblock, axis=1)  # (K, B), static
-    # 2D iota (TPU requires >= 2D), flattened for the axis-1 gather
+    if mode == "row":
+        return scales
+    if mode == "lanes":
+        bpt = B // qblock
+        return repeat_lanes(scales, (i % (LANES // bpt)) * bpt, bpt, qblock)
     pos = jax.lax.broadcasted_iota(jnp.int32, (1, B), 1).reshape(B) + i * B
     return jnp.take(scales, pos // qblock, axis=1, mode="clip")
 
@@ -163,9 +200,7 @@ def _row_coeff(w_ref, g_ref):
     return w
 
 
-def _dq_superpose_kernel(
-    scale_ref, w_ref, *refs, qblock=0, aligned=False, gained=False
-):
+def _dq_superpose_kernel(scale_ref, w_ref, *refs, qblock=0, mode="row", gained=False):
     """Dequantize pre-quantized rows and superpose: acc = sum_k w_k s_k q_k
     (times the per-row channel gain g_k in the gain-aware variant).
 
@@ -182,32 +217,33 @@ def _dq_superpose_kernel(
     g_ref, (q_ref, o_ref) = (refs[0], refs[1:]) if gained else (None, refs)
     i = pl.program_id(0)
     K, B = q_ref.shape
-    scale = _tile_scale_cols(scale_ref, i, K, B, qblock, aligned)
+    scale = _tile_scale_cols(scale_ref, i, K, B, qblock, mode)
     dq = q_ref[...].astype(jnp.float32) * scale
     o_ref[...] = jnp.sum(dq * _row_coeff(w_ref, g_ref), axis=0).reshape(o_ref.shape)
 
 
 def _dq_superpose_int4_kernel(
-    scale_ref, w_ref, *refs, qblock=0, aligned=False, gained=False
+    scale_ref, w_ref, *refs, qblock=0, mode="row", gained=False
 ):
     """int4 variant: unpack two symbols per byte in-VMEM, then dequant+sum.
 
-    p_ref: (K, B//2) uint8 tile of row-major packed nibbles; the HBM read
-    for a 4-bit cohort is 1/8 of the f32 path. Block ids index *symbol*
+    p_ref: (K, B//2) uint8 tile of planar packed nibbles
+    (``_unpack_tile``); the HBM read for a 4-bit cohort is 1/8 of the
+    f32 path. Block ids index *symbol*
     positions (two per packed byte), so the scale expansion happens
     after the in-VMEM unpack.
     """
     g_ref, (p_ref, o_ref) = (refs[0], refs[1:]) if gained else (None, refs)
     i = pl.program_id(0)
-    q = _unpack_nibbles(p_ref[...])
+    q = _unpack_tile(p_ref[...])
     K, B = q.shape
-    scale = _tile_scale_cols(scale_ref, i, K, B, qblock, aligned)
+    scale = _tile_scale_cols(scale_ref, i, K, B, qblock, mode)
     dq = q.astype(jnp.float32) * scale
     o_ref[...] = jnp.sum(dq * _row_coeff(w_ref, g_ref), axis=0).reshape(o_ref.shape)
 
 
 def _fold_superpose_kernel(
-    scale_ref, w_ref, *refs, qblock=0, aligned=False, gained=False
+    scale_ref, w_ref, *refs, qblock=0, mode="row", gained=False
 ):
     """Streaming fold: out = acc + sum_k w_k s_k q_k (DESIGN.md §11).
 
@@ -225,40 +261,42 @@ def _fold_superpose_kernel(
     g_ref, (q_ref, acc_ref, o_ref) = (refs[0], refs[1:]) if gained else (None, refs)
     i = pl.program_id(0)
     K, B = q_ref.shape
-    scale = _tile_scale_cols(scale_ref, i, K, B, qblock, aligned)
+    scale = _tile_scale_cols(scale_ref, i, K, B, qblock, mode)
     dq = q_ref[...].astype(jnp.float32) * scale
     part = jnp.sum(dq * _row_coeff(w_ref, g_ref), axis=0)
     o_ref[...] = acc_ref[...] + part.reshape(o_ref.shape)
 
 
 def _fold_superpose_int4_kernel(
-    scale_ref, w_ref, *refs, qblock=0, aligned=False, gained=False
+    scale_ref, w_ref, *refs, qblock=0, mode="row", gained=False
 ):
     """int4 fold variant: in-VMEM nibble unpack, then fold into acc."""
     g_ref, (p_ref, acc_ref, o_ref) = (refs[0], refs[1:]) if gained else (None, refs)
     i = pl.program_id(0)
-    q = _unpack_nibbles(p_ref[...])
+    q = _unpack_tile(p_ref[...])
     K, B = q.shape
-    scale = _tile_scale_cols(scale_ref, i, K, B, qblock, aligned)
+    scale = _tile_scale_cols(scale_ref, i, K, B, qblock, mode)
     dq = q.astype(jnp.float32) * scale
     part = jnp.sum(dq * _row_coeff(w_ref, g_ref), axis=0)
     o_ref[...] = acc_ref[...] + part.reshape(o_ref.shape)
 
 
-def _packed_specs(q, scale, *, qblock, packed4):
+def _packed_specs(q, scale, *, qblock, packed4, interpret):
     """Shared scaffolding of the packed superpose/fold calls.
 
-    Returns (M, grid, in_specs, scales, w_spec_args...) — the grid, the
-    normalized (and, in the aligned case, padded) scale matrix, and the
+    Returns (M, grid, mode, scales, smat, col, tile): the logical symbol
+    count, the grid, the scale mode of ``_tile_scale_cols``, the
+    normalized (and, in ``"lanes"`` mode, padded) scale matrix, and the
     BlockSpecs for (scale matrix, per-client column, symbol tile).
 
-    Scale streaming: when qblock divides the logical tile width (every
-    power-of-two block size <= BLOCK_COLS, incl. the 256 default), each
-    grid step owns a contiguous (K, BLOCK_COLS/qblock) scale slice — a
-    streamed BlockSpec, VMEM-safe at any M. The scale matrix is padded
-    with 1.0 to the grid's block count (lane padding symbols are exact
-    zeros, so the scale value multiplied there never shows). Unaligned
-    sizes keep the whole matrix resident + in-kernel gather.
+    Scale streaming (``"lanes"``): the TPU block rule wants a block's
+    minor dim to be a multiple of 128 or the whole axis, so the scale
+    matrix streams in (K, 128) blocks, each covering 128 quantization
+    blocks. It is padded with 1.0 to a whole number of such blocks
+    (padding symbols are exact zeros, so that scale never shows). Any
+    other blockwise qblock keeps the whole matrix resident for an
+    in-kernel gather, which only interpret mode runs: on TPU it is a
+    ValueError naming the value.
     """
     K, cols = q.shape
     bc = BLOCK_COLS // 2 if packed4 else BLOCK_COLS
@@ -271,18 +309,21 @@ def _packed_specs(q, scale, *, qblock, packed4):
     grid = (cols // bc,)
     col = pl.BlockSpec((K, 1), lambda i: (0, 0))
     tile = pl.BlockSpec((K, bc), lambda i: (0, i))
-    aligned = qblock > 0 and n_blocks > 1 and BLOCK_COLS % qblock == 0
-    if aligned:
-        bpt = BLOCK_COLS // qblock  # blocks per tile
-        need = grid[0] * bpt
-        if n_blocks < need:
-            scales = jnp.pad(
-                scales, ((0, 0), (0, need - n_blocks)), constant_values=1.0
-            )
-        smat = pl.BlockSpec((K, bpt), lambda i: (0, i))
-    else:
-        smat = pl.BlockSpec((K, n_blocks), lambda i: (0, 0))
-    return M, grid, aligned, scales, smat, col, tile
+    if qblock <= 0 or n_blocks == 1:
+        return M, grid, "row", scales, col, col, tile
+    if qblock % LANES == 0 and BLOCK_COLS % qblock == 0:
+        tps = LANES // (BLOCK_COLS // qblock)  # grid steps per scale block
+        need = -(-grid[0] // tps) * LANES
+        scales = jnp.pad(scales, ((0, 0), (0, need - n_blocks)), constant_values=1.0)
+        smat = pl.BlockSpec((K, LANES), lambda i: (0, i // tps))
+        return M, grid, "lanes", scales, smat, col, tile
+    if not interpret:
+        raise ValueError(
+            f"qblock={qblock}: the TPU data plane needs a blockwise scale "
+            f"size that is a multiple of {LANES} and divides {BLOCK_COLS}"
+        )
+    smat = pl.BlockSpec((K, n_blocks), lambda i: (0, 0))
+    return M, grid, "gather", scales, smat, col, tile
 
 
 def ota_packed_2d(
@@ -298,7 +339,7 @@ def ota_packed_2d(
     """Dequant + weighted superpose of quantized client rows.
 
     q: (K, M) int8/int16/f32 symbols, or (K, M//2) uint8 when ``packed4``
-    (row-major int4 nibbles; logical M = 2 * q.shape[1]). scale: (K,) or
+    (planar int4 nibbles; logical M = 2 * q.shape[1]). scale: (K,) or
     (K, 1) per-update scales, or the (K, n_blocks) blockwise scale
     matrix with ``qblock`` symbols per block (``core/quant.
     quantize_row_sr`` with block = qblock; last block ragged). w: (K,).
@@ -311,8 +352,8 @@ def ota_packed_2d(
     core/ota.py).
     """
     K = q.shape[0]
-    M, grid, aligned, scales, smat, col, tile = _packed_specs(
-        q, scale, qblock=qblock, packed4=packed4
+    M, grid, mode, scales, smat, col, tile = _packed_specs(
+        q, scale, qblock=qblock, packed4=packed4, interpret=interpret
     )
     body = _dq_superpose_int4_kernel if packed4 else _dq_superpose_kernel
     gained = gains is not None
@@ -322,7 +363,7 @@ def ota_packed_2d(
         operands.append(jnp.asarray(gains).reshape(K, 1).astype(jnp.float32))
     operands.append(q)
     return pl.pallas_call(
-        functools.partial(body, qblock=qblock, aligned=aligned, gained=gained),
+        functools.partial(body, qblock=qblock, mode=mode, gained=gained),
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((BLOCK_COLS,), lambda i: (i,)),
@@ -351,11 +392,11 @@ def ota_fold_2d(
     of one (K, M) barrier, and HBM traffic per fold is one read of the
     batch symbols + one read/write of the accumulator. ``gains``: the
     optional per-row channel gain column as in ``ota_packed_2d``.
-    Oracle: ``ref.ota_fold_ref`` (bit-equal).
+    Oracle: ``ref.ota_fold_ref`` (within ``ref.ota_fold_bound``).
     """
     K = q.shape[0]
-    M, grid, aligned, scales, smat, col, tile = _packed_specs(
-        q, scale, qblock=qblock, packed4=packed4
+    M, grid, mode, scales, smat, col, tile = _packed_specs(
+        q, scale, qblock=qblock, packed4=packed4, interpret=interpret
     )
     assert acc.shape == (M,), (acc.shape, M)
     body = _fold_superpose_int4_kernel if packed4 else _fold_superpose_kernel
@@ -367,7 +408,7 @@ def ota_fold_2d(
         operands.append(jnp.asarray(gains).reshape(K, 1).astype(jnp.float32))
     operands.extend([q, acc.astype(jnp.float32)])
     return pl.pallas_call(
-        functools.partial(body, qblock=qblock, aligned=aligned, gained=gained),
+        functools.partial(body, qblock=qblock, mode=mode, gained=gained),
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((BLOCK_COLS,), lambda i: (i,)),

@@ -80,24 +80,23 @@ def ota_packed_ref(
     """Oracle for the packed-uplink dequant+superpose kernel
     (``ota_fused.ota_packed_2d``).
 
-    q: (K, M) int8/int16/f32 symbols, or (K, M//2) uint8 row-major int4
+    q: (K, M) int8/int16/f32 symbols, or (K, M//2) uint8 planar int4
     nibbles when ``packed4``. scale: (K,)/(K, 1) per-update scales, or
     the (K, n_blocks) blockwise scale matrix — symbol position p
     dequantizes with block p // qblock (``qblock`` = 0 or n_blocks = 1:
     one scale per update, the PR-2 format). w: (K,). ``gains``: optional
     (K,) effective channel gain per row (DESIGN.md §12) — the combining
     coefficient becomes w_k * g_k, multiplied out BEFORE the symbol
-    math exactly as the kernel's ``_row_coeff`` does, so kernel and
-    oracle stay bit-equal with and without gains (None skips the
+    math exactly as the kernel's ``_row_coeff`` does (None skips the
     multiply entirely: the legacy program). Returns the (M,) f32
-    partial aggregate sum_k w_k [* g_k] * scale_k[block] * q_k. Uses
-    the same nibble unpack and per-column scale gather as the kernel
-    body so the two are bit-equal per storage group.
+    partial aggregate sum_k w_k [* g_k] * scale_k[block] * q_k. Every
+    term is the kernel's own product; the two differ only in the order
+    of the K-row sum, within ``ota_fold_bound``.
     """
     if packed4:
-        from repro.kernels.ota_fused import _unpack_nibbles
+        from repro.kernels.ops import unpack_int4_rows
 
-        q = _unpack_nibbles(q)
+        q = unpack_int4_rows(q)
     K, M = q.shape
     scales = jnp.asarray(scale, jnp.float32)
     if scales.ndim == 1:
@@ -129,15 +128,54 @@ def ota_fold_ref(
     acc: the running (M,) f32 superposition state; remaining args as in
     ``ota_packed_ref`` (incl. the optional per-row channel ``gains``).
     Returns acc + sum_k w_k [* g_k] * scale_k[block] * q_k — the
-    per-column math of the barrier oracle plus one elementwise add,
-    so kernel and oracle are bit-equal and fold(zeros, batch) equals
-    ``ota_packed_ref(batch)`` (the persistent-accumulator contract,
-    DESIGN.md §11). A wave whose gains are all zero adds exact zeros:
-    the accumulator value is unchanged.
+    per-column math of the barrier oracle plus one elementwise add, so
+    fold(zeros, batch) equals ``ota_packed_ref(batch)`` (the
+    persistent-accumulator contract, DESIGN.md §11) and the kernel
+    agrees within ``ota_fold_bound``. A wave whose gains are all zero
+    adds exact zeros: the accumulator value is unchanged.
     """
     return acc.astype(jnp.float32) + ota_packed_ref(
         q, scale, w, gains=gains, qblock=qblock, packed4=packed4
     )
+
+
+def ota_fold_bound(
+    acc: Optional[jnp.ndarray],
+    q: jnp.ndarray,
+    scale: jnp.ndarray,
+    w: jnp.ndarray,
+    *,
+    gains: Optional[jnp.ndarray] = None,
+    qblock: int = 0,
+    packed4: bool = False,
+) -> jnp.ndarray:
+    """Per-element bound on |kernel - oracle| for the packed superpose
+    (``acc`` None) or fold, where the two differ only in summation order.
+
+    Every term w_k [g_k] s_k q_k is formed by the same multiplies on both
+    sides; what differs is the order of the f32 sum over the K rows (plus
+    the accumulator), which XLA and Mosaic each choose freely. Any order
+    of an n-term f32 sum is within (n - 1) u sum|x_i| of the exact sum
+    (u = 2^-24, Higham, "Accuracy and Stability of Numerical
+    Algorithms", §4.2), so two orders differ by at most twice that. The
+    bound takes n + 2 terms at eps = 2u — room for one fused
+    multiply-add per term — plus n times the smallest normal f32 for
+    flushed subnormals.
+    """
+    if packed4:
+        from repro.kernels.ops import unpack_int4_rows
+
+        q = unpack_int4_rows(q)
+    absq = jnp.abs(q.astype(jnp.float32))
+    absg = None if gains is None else jnp.abs(jnp.asarray(gains, jnp.float32))
+    abss = jnp.abs(jnp.asarray(scale, jnp.float32))
+    mag = ota_packed_ref(absq, abss, jnp.abs(w), gains=absg, qblock=qblock)
+    n = q.shape[0]
+    if acc is not None:
+        mag = mag + jnp.abs(acc.astype(jnp.float32))
+        n += 1
+    info = jnp.finfo(jnp.float32)
+    return (n + 2) * float(info.eps) * mag + n * float(info.tiny)
 
 
 def ota_aggregate_ref(

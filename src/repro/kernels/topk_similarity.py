@@ -8,7 +8,7 @@ return each query's k best records — without ever materialising the
     for each record tile i:
         s      = q @ tile.T                  (MXU; cosine via unit norms)
         s     |= -inf past the live count    (arena capacity padding)
-        topk   = top_k([topk_scores | s])    (running (Q, KP) merge)
+        topk   = k x select-max([topk | s])  (running (Q, KP) merge)
 
 The running top-k (scores + record indices) lives in the two output refs,
 revisited every grid step — the same sequential-grid accumulation pattern
@@ -17,14 +17,15 @@ storage class of ``retrieval/arena.py``) are dequantized in-pass from
 their (TILE_N, D/qblock) scale-grid slice, so the HBM read of an int8
 store is ~1/3.8 of the f32 slab.
 
-Tie contract (the bit-equality anchor): descending score, equal scores by
-ascending record index. ``jax.lax.top_k`` keeps the lower candidate
-position on ties, and every merge concatenates the running list (all
-indices from earlier tiles, already tie-ordered) before the current tile
-(ascending positions), so the invariant holds inductively and the result
-is exactly the top-k a stable brute-force scan produces. The jnp oracle
-(``ref.topk_similarity_ref``) replays the identical tile loop, so kernel
-and oracle are bit-equal in interpret mode.
+Tie contract: descending score, equal scores by ascending record index.
+The merge keeps the lower candidate position on ties, as ``lax.top_k``
+does, and every merge concatenates the running list (all indices from
+earlier tiles, already tie-ordered) before the current tile (ascending
+positions), so the invariant holds inductively and the result is
+exactly the top-k a stable brute-force scan produces. The jnp oracle
+(``ref.topk_similarity_ref``) replays the same tile loop with
+``lax.top_k``, so kernel and oracle agree bit for bit in interpret mode;
+on the chip the MXU dot may round differently from XLA's.
 
 The live record count ``n`` is a *traced* scalar: the arena hands the
 kernel its zero-padded capacity slab, so the jit cache keys on
@@ -38,12 +39,22 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.ota_fused import repeat_lanes
+
 TILE_N = 256  # records per grid step; arena capacity is a multiple
 TOPK_LANES = 128  # running top-k width (one lane tile); k <= TOPK_LANES
 
 
-def _merge_topk(score_ref, idx_ref, s, pos, i):
-    """Fold one tile's (Q, T) scores into the running (Q, KP) top-k."""
+def _merge_topk(score_ref, idx_ref, s, pos, i, k):
+    """Fold one tile's (Q, T) scores into the running (Q, KP) top-k.
+
+    k rounds of select-max over the candidates [running | tile]: take
+    the largest live score, the lowest candidate position holding it,
+    and retire that position. Lowest position among ties is exactly what
+    ``lax.top_k`` keeps (Mosaic has no lowering for it), so the running
+    lanes match the oracle's first k lanes; lanes past k stay -inf.
+    """
+
     @pl.when(i == 0)
     def _init():
         score_ref[...] = jnp.full(score_ref.shape, -jnp.inf, jnp.float32)
@@ -51,9 +62,31 @@ def _merge_topk(score_ref, idx_ref, s, pos, i):
 
     cand_s = jnp.concatenate([score_ref[...], s], axis=1)
     cand_i = jnp.concatenate([idx_ref[...], pos], axis=1)
-    v, a = jax.lax.top_k(cand_s, score_ref.shape[1])
-    score_ref[...] = v
-    idx_ref[...] = jnp.take_along_axis(cand_i, a, axis=1)
+    Q, W = cand_s.shape
+    lane = jax.lax.broadcasted_iota(jnp.int32, (Q, W), 1)
+    out_lane = jax.lax.broadcasted_iota(jnp.int32, score_ref.shape, 1)
+
+    def select(r, carry):
+        taken, out_s, out_i = carry
+        live = jnp.where(taken > 0, -jnp.inf, cand_s)
+        best = jnp.max(live, axis=1, keepdims=True)
+        first = jnp.min(
+            jnp.where((taken == 0) & (live == best), lane, W), axis=1, keepdims=True
+        )
+        hit = lane == first
+        got = jnp.sum(jnp.where(hit, cand_i, 0), axis=1, keepdims=True)
+        out_s = jnp.where(out_lane == r, best, out_s)
+        out_i = jnp.where(out_lane == r, got, out_i)
+        return jnp.where(hit, 1, taken), out_s, out_i
+
+    init = (
+        jnp.zeros((Q, W), jnp.int32),
+        jnp.full(score_ref.shape, -jnp.inf, jnp.float32),
+        jnp.zeros(idx_ref.shape, jnp.int32),
+    )
+    _, out_s, out_i = jax.lax.fori_loop(0, k, select, init)
+    score_ref[...] = out_s
+    idx_ref[...] = out_i
 
 
 def _tile_scores(q, rec, i, n):
@@ -63,38 +96,40 @@ def _tile_scores(q, rec, i, n):
     return jnp.where(pos < n, s, -jnp.inf), pos
 
 
-def _topk_f32_kernel(n_ref, q_ref, r_ref, score_ref, idx_ref):
+def _topk_f32_kernel(n_ref, q_ref, r_ref, score_ref, idx_ref, *, k):
     i = pl.program_id(0)
     s, pos = _tile_scores(q_ref[...], r_ref[...], i, n_ref[0, 0])
-    _merge_topk(score_ref, idx_ref, s, pos, i)
+    _merge_topk(score_ref, idx_ref, s, pos, i, k)
 
 
-def _topk_int8_kernel(n_ref, q_ref, r_ref, s_ref, score_ref, idx_ref, *, qblock):
+def _topk_int8_kernel(n_ref, q_ref, r_ref, s_ref, score_ref, idx_ref, *, qblock, k):
     """int8 variant: dequantize the record tile in-VMEM from its blockwise
     scale slice (``qblock`` dims per scale, the arena storage class)."""
     i = pl.program_id(0)
-    rec = r_ref[...].astype(jnp.float32) * jnp.repeat(
-        s_ref[...].astype(jnp.float32), qblock, axis=1
+    scales = s_ref[...].astype(jnp.float32)
+    rec = r_ref[...].astype(jnp.float32) * repeat_lanes(
+        scales, 0, scales.shape[1], qblock
     )
     s, pos = _tile_scores(q_ref[...], rec, i, n_ref[0, 0])
-    _merge_topk(score_ref, idx_ref, s, pos, i)
+    _merge_topk(score_ref, idx_ref, s, pos, i, k)
 
 
-def topk_similarity_2d(qm, recs, scales, n, *, interpret: bool = False):
+def topk_similarity_2d(
+    qm, recs, scales, n, *, k: int = TOPK_LANES, interpret: bool = False
+):
     """qm: (Qp, D) f32 queries; recs: (Np, D) f32 or int8 record slab with
     Np % TILE_N == 0 (the arena capacity buffer, zero-padded); scales:
     (Np, D // qblock) f32 scale grid for int8 recs, None for f32; n: ()
     live record count (positions >= n score -inf).
 
     Returns (scores (Qp, TOPK_LANES) f32, idx (Qp, TOPK_LANES) int32),
-    each row sorted by the tie contract; entries past min(n, TOPK_LANES)
-    are -inf. ``jax.lax.top_k`` inside the body is exercised in interpret
-    mode (the CPU contract of this repo); on real TPU it requires a
-    Mosaic lowering — fall back to the jnp oracle if unsupported.
+    each row sorted by the tie contract. The first k <= TOPK_LANES
+    lanes are the top-k; lanes past k (and past n) are -inf.
     """
     Qp, D = qm.shape
     Np = recs.shape[0]
     assert Np % TILE_N == 0, (Np, TILE_N)
+    assert 0 < k <= TOPK_LANES, k
     grid = (Np // TILE_N,)
     scalar = pl.BlockSpec((1, 1), lambda i: (0, 0))
     qspec = pl.BlockSpec((Qp, D), lambda i: (0, 0))
@@ -113,7 +148,7 @@ def topk_similarity_2d(qm, recs, scales, n, *, interpret: bool = False):
         assert D % nb == 0, (D, nb)
         sspec = pl.BlockSpec((TILE_N, nb), lambda i: (i, 0))
         return pl.pallas_call(
-            functools.partial(_topk_int8_kernel, qblock=D // nb),
+            functools.partial(_topk_int8_kernel, qblock=D // nb, k=k),
             grid=grid,
             in_specs=[scalar, qspec, rspec, sspec],
             out_specs=out_specs,
@@ -121,7 +156,7 @@ def topk_similarity_2d(qm, recs, scales, n, *, interpret: bool = False):
             interpret=interpret,
         )(n2d, qm, recs, scales)
     return pl.pallas_call(
-        _topk_f32_kernel,
+        functools.partial(_topk_f32_kernel, k=k),
         grid=grid,
         in_specs=[scalar, qspec, rspec],
         out_specs=out_specs,

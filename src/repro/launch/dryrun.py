@@ -251,10 +251,7 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool = False,
         "multi_pod": multi_pod,
     }
 
-    from repro.util import use_mesh
-
-    # jax.set_mesh on new jax, `with mesh:` on 0.4.x
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         try:
             # ---- the deliverable: full production config lowers + compiles
             t0 = time.time()

@@ -11,13 +11,10 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """Version-compat ``jax.make_mesh`` (jax < 0.5 has no AxisType; plain
-    make_mesh gives the same Auto axes there)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis Auto (compiler-chosen sharding
+    inside jit, explicit specs at the boundaries)."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

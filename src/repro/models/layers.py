@@ -17,7 +17,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
-from repro.util import get_abstract_mesh
 
 Params = Dict[str, Any]
 
@@ -522,7 +521,7 @@ def _moe_math_local(xf, p, E: int, K: int, cap_factor: float):
 
 
 def _mesh_info():
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty:
         return None
     sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
@@ -621,11 +620,10 @@ def moe_block(
         out = weighted.reshape(T_loc, K, d).sum(axis=1).astype(xf.dtype)
         return out, aux
 
-    mesh = get_abstract_mesh()
-    from jax.experimental.shard_map import shard_map
+    mesh = jax.sharding.get_abstract_mesh()
 
     dp_entry = dp_axes if len(dp_axes) > 1 else dp_axes[0]
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(None, None),            # router (replicated)
                   P("model", None, None),   # w_gate: expert slice
@@ -633,7 +631,7 @@ def moe_block(
                   P("model", None, None),   # w_down
                   P(dp_entry, None)),       # tokens: (T, d) over dp
         out_specs=(P(dp_entry, None), P()),
-        check_rep=False,
+        check_vma=False,
     )(p["router"], p["w_gate"], p["w_up"], p["w_down"], x.reshape(T, d))
     out = out.reshape(B, S, d)
 

@@ -12,11 +12,13 @@ equal scores by ascending record index. Three implementations share it:
   exactly, scores included;
 - the Pallas kernel / jnp-oracle path (``kernels.ops.topk_cosine``) —
   the TPU path, streamed over record tiles with a running in-kernel
-  top-k, bit-equal to its oracle.
+  top-k, bit-equal to its oracle in interpret mode.
 
 As with the OTA data plane, the kernel runs by default only on TPU
 (interpret-mode Pallas is a correctness tool); off-TPU the engine uses
-the numpy path unless ``use_kernel`` forces otherwise.
+the numpy path unless ``use_kernel`` forces otherwise. The device path
+keeps at most ``TOPK_LANES`` candidates, so a larger k there is a
+ValueError, never a silent switch to the host.
 
 Mesh sharding (DESIGN.md §15): construct the engine with ``mesh`` (a
 ``data``-axis device mesh) to place the slab rows across devices — the
@@ -154,16 +156,24 @@ class RetrievalEngine:
         if n == 0 or k <= 0 or q == 0:
             return np.zeros((q, 0), np.float32), np.zeros((q, 0), np.int32)
         with obs.span("retrieval.query", q=q, k=k, rows=n):
-            obs.metrics.inc("retrieval.queries", q)
             obs.metrics.inc("retrieval.query_rows", q * n)
             use_kernel = self.use_kernel
             if use_kernel is None:
                 use_kernel = _default_use_kernel()
+            from repro.kernels.ops import kernel_path
             from repro.kernels.topk_similarity import TOPK_LANES
 
-            if self.mesh is not None and k <= TOPK_LANES:
+            device = use_kernel or self.mesh is not None
+            path = kernel_path(use_kernel) if device else "numpy"
+            obs.metrics.inc("retrieval.queries", q, path=path)
+
+            if device and k > TOPK_LANES:
+                raise ValueError(
+                    f"k={k}: the device top-k keeps at most {TOPK_LANES} lanes"
+                )
+            if self.mesh is not None:
                 return self._topk_jax_sharded(queries, k, use_kernel)
-            if use_kernel and k <= TOPK_LANES:
+            if use_kernel:
                 return self._topk_jax(queries, k)
             if self.n_shards > 1:
                 return self._topk_numpy_sharded(queries, k)

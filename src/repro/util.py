@@ -1,105 +1,21 @@
 """Small shared utilities: mesh-aware sharding constraints, dtypes, trees."""
 from __future__ import annotations
 
-import contextlib
-
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-
-
-class _EmptyMesh:
-    """Stands in for an empty abstract mesh on jax versions without one."""
-
-    empty = True
-    axis_names: tuple = ()
-    axis_sizes: tuple = ()
-
-
-_EMPTY_MESH = _EmptyMesh()
-
-
-def get_abstract_mesh():
-    """Version-compat ambient mesh lookup.
-
-    ``jax.sharding.get_abstract_mesh`` only exists on jax >= 0.5; on 0.4.x
-    the ambient mesh set by ``with mesh:`` lives in
-    ``jax._src.mesh.thread_resources``. Both sources yield an object
-    exposing ``.empty`` / ``.axis_names`` / ``.axis_sizes``, which is all
-    callers here use; whichever holds a non-empty mesh wins (so both
-    ``jax.set_mesh`` and the legacy ``with mesh:`` context activate the
-    mesh-aware code paths). With neither set we report an empty mesh and
-    callers degrade to their single-device behaviour.
-    """
-    abstract = None
-    getter = getattr(jax.sharding, "get_abstract_mesh", None)
-    if getter is not None:
-        abstract = getter()
-        if not abstract.empty:
-            return abstract
-    try:
-        physical = __import__("jax._src.mesh", fromlist=["thread_resources"]
-                              ).thread_resources.env.physical_mesh
-        if not physical.empty:
-            return physical
-    except Exception:  # pragma: no cover - private API moved
-        pass
-    return abstract if abstract is not None else _EMPTY_MESH
-
-
-# Meshes activated through use_mesh on jax versions where jax.set_mesh is
-# a bare global setter (no context manager, no read-back API): we keep our
-# own stack so nested/sequential use_mesh blocks restore the outer mesh on
-# exit instead of leaking the inner one into the rest of the process.
-_MESH_STACK: list = []
-
-
-@contextlib.contextmanager
-def use_mesh(mesh):
-    """Activate ``mesh`` as the ambient mesh across jax versions.
-
-    jax >= 0.5 spells this ``jax.set_mesh`` (a context manager in recent
-    releases, a global setter before that); 0.4.x uses the ``with mesh:``
-    Mesh context. ``get_abstract_mesh`` above reads back either form. On
-    the global-setter variant the previous mesh is saved and restored on
-    exit (``None`` — "no ambient mesh" — when this is the outermost
-    block), so servers switching meshes mid-process don't leak the inner
-    mesh past the ``with``.
-    """
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is None:
-        with mesh:
-            yield
-        return
-    ctx = set_mesh(mesh)
-    if hasattr(ctx, "__enter__"):
-        with ctx:
-            yield
-        return
-    _MESH_STACK.append(mesh)
-    try:
-        yield
-    finally:
-        _MESH_STACK.pop()
-        if _MESH_STACK:
-            set_mesh(_MESH_STACK[-1])
-        else:
-            try:
-                set_mesh(None)
-            except Exception:  # pragma: no cover - a jax.set_mesh that
-                pass           # rejects None leaves no unset API;
-                               # best-effort clear at the outermost level
 
 
 def constrain(x: jnp.ndarray, spec: P) -> jnp.ndarray:
     """``with_sharding_constraint`` that no-ops when no mesh is active.
 
     Models call this on large intermediates (MoE dispatch buffers, SSM
-    channel states). Under an active mesh the constraint binds; in
+    channel states). Under an active mesh (``jax.set_mesh``) the
+    constraint binds; in
     single-device unit tests it silently disappears. Axis names
     not present in the active mesh are dropped from the spec.
     """
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty:
         return x
     sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))

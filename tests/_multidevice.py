@@ -35,6 +35,9 @@ def run_multidevice(
     in the failure message so pytest shows the real traceback.
     """
     env = dict(os.environ)
+    # forced host devices exist on the CPU backend only, and a child
+    # must never reach for a TPU its parent may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = REPO_SRC
     out = subprocess.run(

@@ -264,10 +264,12 @@ def test_dequant_superpose_accepts_blockwise_scale_matrix():
     q = jnp.asarray(rng.randint(-127, 128, size=(K, m)), jnp.int8)
     got = ops.ota_dequant_superpose(q, scales, w, qblock=qblock)
     want = ref.ota_packed_ref(q, scales, w, qblock=qblock)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # same products, K-row sum in another order: the f32 summation bound
+    bound = ref.ota_fold_bound(None, q, scales, w, qblock=qblock)
+    err = np.abs(np.asarray(got) - np.asarray(want))
+    assert (err <= np.asarray(bound)).all(), float(err.max())
     # and the gather agrees with an explicit per-column expansion
     expand = jnp.repeat(scales, qblock, axis=1)
     manual = jnp.sum(q.astype(jnp.float32) * expand * w.reshape(-1, 1), axis=0)
-    np.testing.assert_allclose(
-        np.asarray(got), np.asarray(manual), rtol=1e-6, atol=1e-7
-    )
+    err = np.abs(np.asarray(got) - np.asarray(manual))
+    assert (err <= np.asarray(bound)).all(), float(err.max())

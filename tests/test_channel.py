@@ -1,11 +1,13 @@
 """Physical OTA channel model (DESIGN.md §12): property tests pinning the
 channel math bit-for-bit.
 
-Four contracts, each asserted with exact (``==``) float equality:
+Four contracts, the last three asserted with exact (``==``) float
+equality:
 
-- kernel == oracle: the gain-aware Pallas pass (``ota_packed_2d`` /
-  ``ota_fold_2d`` with ``gains=``) matches the jnp oracles bitwise for
-  every storage class, including truncated (zero-gain) rows;
+- kernel vs oracle: the gain-aware Pallas pass (``ota_packed_2d`` /
+  ``ota_fold_2d`` with ``gains=``) matches the jnp oracles for every
+  storage class, including truncated (zero-gain) rows, within the f32
+  summation-order bound ``ref.ota_fold_bound``;
 - ``gains=None`` regression: the unit channel is bitwise identical to
   the pre-channel aggregation, in barrier and streaming modes;
 - truncation == exclusion: zero-gain rows contribute exactly nothing —
@@ -66,6 +68,14 @@ def _group(rows):
     return data, scale, qblock, kind == "int4"
 
 
+def _assert_within(got, want, bound):
+    """Kernel vs oracle: the same products summed over the K rows in
+    different orders — |got - want| stays within ``ref.ota_fold_bound``
+    (the f32 summation-order bound), elementwise."""
+    err = np.abs(np.asarray(got) - np.asarray(want))
+    assert (err <= np.asarray(bound)).all(), float(err.max())
+
+
 def _gains(rng, k, zero_first=True):
     g = rng.rand(k).astype(np.float32)
     if zero_first:
@@ -91,7 +101,9 @@ def test_gain_superpose_kernel_matches_oracle(seed, storage):
                            packed4=packed4, interpret=True)
     want = kref.ota_packed_ref(data, scale, w, gains=g, qblock=qblock,
                                packed4=packed4)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    bound = kref.ota_fold_bound(None, data, scale, w, gains=g,
+                                qblock=qblock, packed4=packed4)
+    _assert_within(got, want, bound)
 
 
 @settings(deadline=None, max_examples=5)
@@ -108,7 +120,9 @@ def test_gain_fold_kernel_matches_oracle(seed, storage):
                          packed4=packed4, interpret=True)
     want = kref.ota_fold_ref(acc, data, scale, w, gains=g, qblock=qblock,
                              packed4=packed4)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    bound = kref.ota_fold_bound(acc, data, scale, w, gains=g,
+                                qblock=qblock, packed4=packed4)
+    _assert_within(got, want, bound)
 
 
 def test_unit_gains_bitwise_identical_superpose():

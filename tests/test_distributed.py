@@ -24,9 +24,8 @@ def test_shard_map_moe_matches_local():
         moe_p = jax.tree.map(lambda a: a[0], params["layers"]["moe"])
         out_ref, _ = moe_block(moe_p, x, cfg)
         from repro.launch.mesh import make_mesh
-        from repro.util import use_mesh
         mesh = make_mesh((2, 4), ("data", "model"))
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             out_sm, _ = jax.jit(lambda p_, x_: moe_block(p_, x_, cfg))(moe_p, x)
         err = float(jnp.abs(out_ref - out_sm).max())
         assert err < 1e-5, err
@@ -55,7 +54,6 @@ def test_sharded_train_step_runs_and_matches_single_device():
         ref_loss = float(ref_metrics["loss"])
 
         from repro.launch.mesh import make_mesh
-        from repro.util import use_mesh
         mesh = make_mesh((2, 4), ("data", "model"))
         state_shapes = jax.eval_shape(lambda: state)
         state_specs = {
@@ -71,7 +69,7 @@ def test_sharded_train_step_runs_and_matches_single_device():
         batch_specs = shd.batch_spec(
             {k: jax.ShapeDtypeStruct(v.shape, v.dtype) for k, v in batch.items()}, mesh
         )
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             jitted = jax.jit(
                 make_train_step(model, opt),
                 in_shardings=(
@@ -98,7 +96,7 @@ def test_constrain_filters_indivisible_dims():
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
         from repro.launch.mesh import make_mesh
-        from repro.util import constrain, use_mesh
+        from repro.util import constrain
 
         mesh = make_mesh((2, 4), ("data", "model"))
 
@@ -107,39 +105,35 @@ def test_constrain_filters_indivisible_dims():
             # 7 doesn't divide 4 -> model entry must be dropped, not crash
             return constrain(x, P("data", "model")) * 2
 
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             out = f(jnp.ones((8, 7)))
         assert out.shape == (8, 7)
         print("constrain divisibility guard ok")
     """))
 
 
-def test_use_mesh_global_setter_restores_previous(monkeypatch):
-    """ROADMAP regression: on jax builds where ``jax.set_mesh`` is a bare
-    global setter (not a context manager), nested/sequential ``use_mesh``
-    blocks must restore the outer mesh on exit and clear it (None) at the
-    outermost level — not leak the inner mesh into the process."""
+def test_set_mesh_scope_drives_constrain():
+    """``constrain`` binds only inside ``jax.set_mesh``: outside it is
+    the identity, inside it places by the spec, and leaving the block
+    restores the empty ambient mesh."""
     import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
 
-    from repro import util
+    from repro.launch.mesh import make_host_mesh
+    from repro.util import constrain
 
-    calls = []
-
-    def fake_set_mesh(mesh):
-        calls.append(mesh)
-        return None  # global-setter variant: nothing context-manager-like
-
-    monkeypatch.setattr(jax, "set_mesh", fake_set_mesh, raising=False)
-    a, b = object(), object()
-    with util.use_mesh(a):
-        assert calls == [a]
-        with util.use_mesh(b):
-            assert calls == [a, b]
-        # inner exit must re-activate the outer mesh, not leave b active
-        assert calls == [a, b, a]
-    # outermost exit clears the ambient mesh
-    assert calls == [a, b, a, None]
-    assert util._MESH_STACK == []
+    x = jnp.ones((4, 4))
+    assert constrain(x, P("data")) is x
+    mesh = make_host_mesh()
+    f = jax.jit(lambda a: constrain(a, P("data", "model")) * 2)
+    with jax.set_mesh(mesh):
+        assert not jax.sharding.get_abstract_mesh().empty
+        assert "sharding_constraint" in f.lower(x).as_text()
+        out = f(x)
+    assert jax.sharding.get_abstract_mesh().empty
+    assert "sharding_constraint" not in f.lower(x).as_text()
+    assert float(out.sum()) == 32.0
 
 
 def test_multipod_mesh_axes():

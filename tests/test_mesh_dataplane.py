@@ -110,8 +110,8 @@ def test_ota_one_shard_mesh_byte_identical():
 
 
 def test_ota_sharded_kernel_path_bitwise():
-    # interpret-mode Pallas kernel inside shard_map (check_rep=False is
-    # load-bearing: jax 0.4.x has no pallas_call replication rule)
+    # interpret-mode Pallas kernel inside shard_map (check_vma=False is
+    # load-bearing: pallas_call has no varying-manual-axes rule)
     _ota_case(bits=[4, 8, 16, 8], d_list=(4,), seed=10, use_kernel=True)
 
 

@@ -314,8 +314,11 @@ def test_topk_lanes_bound_enforced():
     st = ArenaStore(256)
     st.add_batch(_unit_rows(300, seed=23))
     queries = _unit_rows(2, seed=24)
-    # k beyond the kernel's running top-k width falls back to numpy
-    scores, idx = RetrievalEngine(st, use_kernel=True).topk(queries, TOPK_LANES + 50)
+    # k beyond the kernel's running top-k width is refused on the device
+    # path (never a silent drop to the host); the numpy path serves it
+    with pytest.raises(ValueError, match=f"k={TOPK_LANES + 50}"):
+        RetrievalEngine(st, use_kernel=True).topk(queries, TOPK_LANES + 50)
+    scores, idx = RetrievalEngine(st, use_kernel=False).topk(queries, TOPK_LANES + 50)
     assert scores.shape == (2, TOPK_LANES + 50)
     s_bf, i_bf = brute_force_topk(st.vectors(), queries, TOPK_LANES + 50)
     np.testing.assert_array_equal(idx, i_bf)

@@ -59,7 +59,12 @@ def test_fold_kernel_matches_oracle(bits, block):
                                packed4=packed4)
     want = kref.ota_fold_ref(acc, data, scale, w, qblock=qblock,
                              packed4=packed4)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # kernel and oracle form the same products and differ only in the
+    # order of the K-row f32 sum: bounded by ref.ota_fold_bound
+    bound = kref.ota_fold_bound(acc, data, scale, w, qblock=qblock,
+                                packed4=packed4)
+    err = np.abs(np.asarray(got) - np.asarray(want))
+    assert (err <= np.asarray(bound)).all(), float(err.max())
 
 
 def test_fold_zero_acc_equals_barrier():

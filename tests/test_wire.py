@@ -21,26 +21,33 @@ def test_pack_int4_rows_roundtrip_odd_even(m):
     rng = np.random.RandomState(m)
     q = jnp.asarray(rng.randint(-8, 8, size=(m,)), jnp.int8)
     p = pack_int4_rows(q)
-    assert p.dtype == jnp.uint8 and p.shape == ((m + 1) // 2,)
+    # whole 256-symbol groups, two symbols per byte
+    assert p.dtype == jnp.uint8 and p.shape == (-(-m // 256) * 128,)
     assert jnp.array_equal(unpack_int4_rows(p, m), q)
+    assert not np.asarray(unpack_int4_rows(p)[m:]).any()  # zero pad
 
 
 def test_pack_int4_rows_2d_and_half_bytes():
     rng = np.random.RandomState(0)
-    q = jnp.asarray(rng.randint(-8, 8, size=(5, 64)), jnp.int8)
+    q = jnp.asarray(rng.randint(-8, 8, size=(5, 512)), jnp.int8)
     p = pack_int4_rows(q)
     assert p.nbytes == q.nbytes // 2
     assert jnp.array_equal(unpack_int4_rows(p), q)
 
 
 def test_pack_int4_rows_is_row_major():
-    # adjacent *elements* share a byte (low nibble first) — the wire
-    # layout the in-kernel unpack depends on, unlike pack_int4's
-    # adjacent-*rows* weight layout
-    q = jnp.asarray([1, -2, 3, -4], jnp.int8)
-    p = np.asarray(pack_int4_rows(q))
-    assert p[0] == (1 | ((-2 & 0xF) << 4))
-    assert p[1] == (3 | ((-4 & 0xF) << 4))
+    # symbols of one row share bytes (never two rows, unlike pack_int4's
+    # weight layout): in each 256-symbol group, byte j holds symbol j
+    # (low nibble) and symbol 128 + j (high nibble) — the planar layout
+    # the in-kernel unpack concatenates without an interleaving reshape
+    q = np.zeros((2, 512), np.int8)
+    q[0, [0, 128, 1, 129, 256, 384]] = [1, -2, 3, -4, 5, -6]
+    p = np.asarray(pack_int4_rows(jnp.asarray(q)))
+    assert p.shape == (2, 256)
+    assert p[0, 0] == (1 | ((-2 & 0xF) << 4))
+    assert p[0, 1] == (3 | ((-4 & 0xF) << 4))
+    assert p[0, 128] == (5 | ((-6 & 0xF) << 4))
+    assert not p[1].any() and not np.delete(p[0], [0, 1, 128]).any()
 
 
 # ---------------------------------------------------------------------------
@@ -178,23 +185,26 @@ def test_dequant_superpose_kernel_matches_ref_direct():
     K, M = 3, 5000
     w = jnp.asarray(rng.uniform(0, 1, K), jnp.float32)
     scale = jnp.asarray(rng.uniform(0.01, 0.2, K), jnp.float32)
+    def within(got, want, bound):
+        # same products, K-row f32 sum in another order (ota_fold_bound)
+        err = np.abs(np.asarray(got) - np.asarray(want))
+        assert (err <= np.asarray(bound)).all(), float(err.max())
+
     for dtype, hi in [(jnp.int8, 127), (jnp.int16, 32767)]:
         q = jnp.asarray(rng.randint(-hi, hi + 1, size=(K, M)), dtype)
         got = ops.ota_dequant_superpose(q, scale, w)
         want = ref.ota_packed_ref(q, scale, w)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        within(got, want, ref.ota_fold_bound(None, q, scale, w))
     q4 = jnp.asarray(rng.randint(-8, 8, size=(K, M)), jnp.int8)
     p4 = pack_int4_rows(q4)
     got = ops.ota_dequant_superpose(p4, scale, w, packed4=True)
     want = ref.ota_packed_ref(p4, scale, w, packed4=True)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    # and both dequantize to the unpacked truth
-    np.testing.assert_allclose(
-        np.asarray(got),
-        np.asarray(ref.ota_packed_ref(q4, scale, w)),
-        rtol=1e-6,
-        atol=1e-6,
-    )
+    within(got, want, ref.ota_fold_bound(None, p4, scale, w, packed4=True))
+    # and both dequantize to the unpacked truth (the planar pack pads
+    # the row to whole 256-symbol groups; the pad symbols are zeros)
+    truth = ref.ota_packed_ref(q4, scale, w)
+    within(got[:M], truth, ref.ota_fold_bound(None, q4, scale, w))
+    np.testing.assert_array_equal(np.asarray(got[M:]), 0.0)
 
 
 def test_degenerate_and_midrange_bits_match_flat_path():
