@@ -45,7 +45,6 @@ from typing import List, Optional, Sequence
 
 import jax.numpy as jnp
 
-from repro import obs
 from repro.core import packing, quant
 from repro.kernels import ops as kops
 
@@ -74,17 +73,7 @@ def encode_row(
     if packing.wire_kind(bits) == "int4":
         q = kops.pack_int4_rows(q)
     qblock = block if int(jnp.asarray(scale).size) > 1 else 0
-    out = packing.PackedRow(data=q, scale=scale, bits=int(bits), qblock=qblock)
-    if obs.is_enabled() and out.kind != "float32":
-        # quantization-MSE proxy (DESIGN.md §14): uniform-dither noise
-        # power E[scale^2]/12 per symbol, from the encoded scales alone —
-        # no reconstruction pass. Device sync, so telemetry-mode only;
-        # the encoded row is identical either way.
-        s = jnp.atleast_1d(jnp.asarray(scale, jnp.float32))
-        obs.metrics.observe(
-            "wire.quant_mse_proxy", float(jnp.mean(s * s)) / 12.0, kind=out.kind
-        )
-    return out
+    return packing.PackedRow(data=q, scale=scale, bits=int(bits), qblock=qblock)
 
 
 def decode_row(row: packing.PackedRow, n: Optional[int] = None) -> jnp.ndarray:

@@ -170,33 +170,48 @@ class FLClient:
         into the returned metrics as uplink metadata, the per-round
         radio report that rides alongside the packed row.
         """
-        jitted, opt = self._step_fn(bits, lr, fedprox_mu)
-        state = {
-            "params": global_params,
-            "opt": opt.init(global_params),
-            "step": jnp.zeros((), jnp.int32),
-        }
-        if fedprox_mu > 0.0:
-            state["anchor"] = global_params
-        rng = np.random.RandomState(seed * 1009 + self.user.user_id)
-        losses = []
-        utts = self.shard.utterances
-        for s in range(local_steps):
-            idx = rng.randint(0, len(utts), size=min(local_batch, len(utts)))
-            batch = batchify(
-                [utts[i] for i in idx], max_frames=max_frames, max_labels=max_labels
-            )
-            batch = {k: jnp.asarray(v) for k, v in batch.items()}
-            state, metrics = jitted(state, batch)
-            losses.append(float(metrics["loss"]))
-        delta = jax.tree.map(
-            lambda new, old: (new.astype(jnp.float32) - old.astype(jnp.float32)),
-            state["params"],
-            global_params,
-        )
-        if layout is not None:
-            delta = packing.pack(delta, layout)
-            if sr_seed is not None:
+        # Host spans of the update's stages (DESIGN.md §14): ``client.batch``
+        # (host batch + transfers), ``client.dispatch`` (the jitted step's
+        # enqueue) and ``client.loss_read`` (the host blocked on the step)
+        # split each local step's wall time.
+        with obs.span("client.update", user=self.user.user_id):
+            jitted, opt = self._step_fn(bits, lr, fedprox_mu)
+            with obs.span("client.setup"):
+                state = {
+                    "params": global_params,
+                    "opt": opt.init(global_params),
+                    "step": jnp.zeros((), jnp.int32),
+                }
+                if fedprox_mu > 0.0:
+                    state["anchor"] = global_params
+            rng = np.random.RandomState(seed * 1009 + self.user.user_id)
+            losses = []
+            utts = self.shard.utterances
+            for s in range(local_steps):
+                with obs.span("client.batch"):
+                    idx = rng.randint(0, len(utts), size=min(local_batch, len(utts)))
+                    batch = batchify(
+                        [utts[i] for i in idx],
+                        max_frames=max_frames,
+                        max_labels=max_labels,
+                    )
+                    batch = {k: jnp.asarray(v) for k, v in batch.items()}
+                with obs.span("client.dispatch"):
+                    state, metrics = jitted(state, batch)
+                with obs.span("client.loss_read"):
+                    losses.append(float(metrics["loss"]))
+            obs.metrics.inc("fl.local_steps", local_steps)
+            with obs.span("client.delta"):
+                delta = jax.tree.map(
+                    lambda new, old: (
+                        new.astype(jnp.float32) - old.astype(jnp.float32)
+                    ),
+                    state["params"],
+                    global_params,
+                )
+                if layout is not None:
+                    delta = packing.pack(delta, layout)
+            if layout is not None and sr_seed is not None:
                 from repro.core import wire
 
                 with obs.span("uplink_encode", bits=bits):

@@ -29,7 +29,6 @@ from repro.obs.trace import (
     get_tracer,
     is_enabled,
     span,
-    traced,
 )
 
 metrics.install_jax_hooks()
@@ -46,5 +45,4 @@ __all__ = [
     "metrics",
     "span",
     "trace",
-    "traced",
 ]

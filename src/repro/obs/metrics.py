@@ -4,12 +4,13 @@
 One module-global :class:`Registry` is the single place the FL data and
 control planes publish numbers into — ``RoundLog``/``StreamRoundLog``
 (round wire legs, loss, satisfaction), ``ota.AggregateInfo`` (uplink
-bytes, truncation, misalignment, noise), the wire codec (quantization
-MSE proxy), the retrieval engine (query counts), the serving engine
-(token throughput), and a ``jax.monitoring`` hook counting jit
-traces/compiles — instead of each subsystem growing ad-hoc report
-fields. Reads are ``snapshot()`` (a plain nested dict, the JSONL/export
-payload) or ``get(name, **labels)`` for one value.
+bytes, truncation, misalignment, noise), the client's local update
+(local steps), the retrieval engine (query counts, arena uploads and
+their bytes), the serving engine (token throughput), and a
+``jax.monitoring`` hook counting jit traces/compiles — instead of each
+subsystem growing ad-hoc report fields. Reads are ``snapshot()`` (a
+plain nested dict, the JSONL/export payload) or ``get(name, **labels)``
+for one value.
 
 Metric taxonomy:
 
@@ -18,7 +19,7 @@ Metric taxonomy:
 - **gauge** — last-write-wins float (``set_gauge``): per-round rates
   (truncation rate, misalignment), losses.
 - **histogram** — running {count, total, min, max} summary
-  (``observe``): staleness discounts, per-row quantization MSE proxy.
+  (``observe``): staleness discounts.
   (No buckets: the consumers are regression diffs and dashboards fed
   from JSONL, not quantile queries.)
 
@@ -28,10 +29,8 @@ Labels: optional keyword labels qualify a series
 distinct.
 
 Publishing is host-arithmetic only (dict update under a lock) and
-always on — the values are already host floats where the calls sit.
-Device-derived extras (the wire MSE proxy) are computed by their call
-sites only while the span tracer is enabled, so the tracer's
-"near-zero overhead when disabled" contract covers the registry too.
+always on — the values are already host numbers where the calls sit;
+no call site reads the device to publish a metric, traced or not.
 
 The jax hook (``install_jax_hooks``, installed on first import of
 ``repro.obs``) listens on ``jax.monitoring`` duration events:

@@ -4,21 +4,32 @@
 One process-global :class:`Tracer` records *spans* — named, nested
 host-time intervals — around the FL round pipeline (``fl/server.py``:
 ``plan -> channel_sample -> client_train -> uplink_encode -> fold ->
-finalize -> optimizer -> broadcast_encode -> feedback``), the retrieval
-query path (``retrieval/engine.py``), and the serving engine's
-prefill/decode steps (``serve/engine.py``). Spans measure *host*
-wall-clock: jax dispatch is asynchronous, so a span around a jitted call
-times dispatch (plus any blocking device transfer inside), not device
-execution — the right clock for finding host-side stalls, retrace storms,
-and stage imbalance in the round loop.
+finalize -> optimizer -> broadcast_encode -> feedback``), each client's
+local update inside ``client_train`` (``fl/client.py``: ``client.update
+> client.setup, client.batch, client.dispatch, client.loss_read,
+client.delta, uplink_encode``), the retrieval query path
+(``retrieval/engine.py``: ``retrieval.query > retrieval.upload``), and
+the serving engine's prefill/decode steps (``serve/engine.py``).
+
+Spans measure *host* wall-clock. jax dispatch is asynchronous, so a span
+around a jitted call times its dispatch, not its device execution; a
+span ends on the device's clock only where its stage ends in a host
+sync: ``client.loss_read`` (the blocking loss read), ``plan`` (the top-k
+results' ``np.asarray``), and ``retrieval.upload``, which waits for its
+transfer while tracing is on (the query blocks on a result of that
+upload anyway). Device time is the profiler's to give: while enabled,
+every span also opens a ``jax.profiler.TraceAnnotation`` of its name, so
+under ``jax.profiler`` tracing the stages sit in the ``.xplane.pb`` host
+plane beside the device's programs, on the same clock.
 
 Design constraints:
 
 - **Near-zero overhead when disabled** (the default): ``span()`` is one
   global attribute check returning a shared no-op context-manager
-  singleton — no allocation, no clock read. The disabled path leaves
-  every instrumented computation byte-identical to the uninstrumented
-  program (spans only observe; ``tests/test_obs.py`` pins this).
+  singleton — no allocation, no clock read, no profiler annotation. The
+  disabled path leaves every instrumented computation byte-identical to
+  the uninstrumented program (spans only observe; ``tests/test_obs.py``
+  pins this).
 - **Monotonic clocks**: timestamps are ``time.perf_counter_ns`` relative
   to the tracer's epoch, exported in microseconds (the ``trace_event``
   unit).
@@ -39,12 +50,13 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import functools
 import json
 import os
 import threading
 import time
 from typing import Any, Dict, Iterator, List, Optional, Set
+
+from jax.profiler import TraceAnnotation
 
 
 @dataclasses.dataclass(frozen=True)
@@ -75,9 +87,10 @@ NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """A live span (context manager); records itself on exit."""
+    """A live span (context manager); records itself on exit and holds a
+    profiler annotation of its name open meanwhile."""
 
-    __slots__ = ("_tracer", "_name", "_args", "_t0", "_depth")
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "_depth", "_note")
 
     def __init__(self, tracer: "Tracer", name: str, args: Optional[Dict[str, Any]]):
         self._tracer = tracer
@@ -88,11 +101,14 @@ class _Span:
         local = self._tracer._local
         self._depth = getattr(local, "depth", 0)
         local.depth = self._depth + 1
+        self._note = TraceAnnotation(self._name)
+        self._note.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc: Any) -> bool:
         t1 = time.perf_counter_ns()
+        self._note.__exit__(None, None, None)
         tracer = self._tracer
         tracer._local.depth = self._depth
         if tracer.enabled:  # disabled mid-span: drop, don't record
@@ -212,25 +228,6 @@ def span(name: str, **args: Any) -> Any:
     if not t.enabled:
         return NULL_SPAN
     return _Span(t, name, args if args else None)
-
-
-def traced(name: Optional[str] = None):
-    """Decorator form: time every call of the wrapped function."""
-
-    def deco(fn):
-        span_name = name or fn.__qualname__
-
-        @functools.wraps(fn)
-        def wrapper(*a: Any, **kw: Any):
-            t = _TRACER
-            if not t.enabled:
-                return fn(*a, **kw)
-            with _Span(t, span_name, None):
-                return fn(*a, **kw)
-
-        return wrapper
-
-    return deco
 
 
 @contextlib.contextmanager

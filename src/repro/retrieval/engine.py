@@ -116,6 +116,29 @@ def normalize_rows(mat: np.ndarray) -> np.ndarray:
     return np.where(norms > 0, mat / np.maximum(norms, 1e-30), mat)
 
 
+def _upload(data: np.ndarray, scales: Optional[np.ndarray]):
+    """Device copies of an arena slab (and its int8 scales), counted
+    (``retrieval.uploads``, ``retrieval.upload_bytes``) and spanned
+    (``retrieval.upload``).
+
+    While tracing is on the span waits for the transfer, so that it
+    times the copy and not its enqueue. That wait costs the round
+    nothing: the query that asked for the upload blocks on its results
+    (``np.asarray``), which depend on these buffers. With tracing off
+    nothing waits here."""
+    import jax
+    import jax.numpy as jnp
+
+    nbytes = data.nbytes + (0 if scales is None else scales.nbytes)
+    obs.metrics.inc("retrieval.uploads")
+    obs.metrics.inc("retrieval.upload_bytes", nbytes)
+    with obs.span("retrieval.upload", nbytes=nbytes):
+        dev = (jnp.asarray(data), None if scales is None else jnp.asarray(scales))
+        if obs.is_enabled():
+            jax.block_until_ready(dev)
+    return dev
+
+
 class RetrievalEngine:
     """Batched cosine top-k queries against one arena."""
 
@@ -241,12 +264,7 @@ class RetrievalEngine:
                     ss = np.concatenate(
                         [ss, np.ones((pad, ss.shape[1]), np.float32)]
                     )
-            cache = (
-                data,
-                len(store),
-                jnp.asarray(dd),
-                None if ss is None else jnp.asarray(ss),
-            )
+            cache = (data, len(store), *_upload(dd, ss))
             self._dev_cache = cache
         with obs.span("shard_merge", shards=n_shards, k=k):
             s, i = topk_cosine_sharded(
@@ -268,12 +286,7 @@ class RetrievalEngine:
         data, scales = self.store.raw()
         cache = self._dev_cache
         if cache is None or cache[0] is not data or cache[1] != len(self.store):
-            cache = (
-                data,
-                len(self.store),
-                jnp.asarray(data),
-                None if scales is None else jnp.asarray(scales),
-            )
+            cache = (data, len(self.store), *_upload(data, scales))
             self._dev_cache = cache
         dev_data, dev_scales = cache[2], cache[3]
         s, i = topk_cosine(

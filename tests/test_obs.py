@@ -1,20 +1,24 @@
 """Telemetry layer (DESIGN.md §14): span tracer semantics (nesting,
-disabled no-op, Perfetto export), the metrics registry (labels,
-histograms, reset), the jit-retrace counter's regression guard, and the
-instrumented round loop's acceptance properties — FLServer and
-StreamingFLServer emit the same span names, the metrics byte counters
-agree bit-for-bit with the ``RoundLog`` that fed them, and a disabled
-tracer leaves round outputs byte-identical."""
+disabled no-op, Perfetto export, spans on the profiler's clock), the
+metrics registry (labels, histograms, reset), the jit-retrace counter's
+regression guard, and the instrumented round loop's acceptance
+properties — FLServer and StreamingFLServer emit the same span names
+down to each client's local-update stages, the per-step spans match the
+``fl.local_steps`` counter, the metrics byte counters agree bit-for-bit
+with the ``RoundLog`` that fed them, the arena upload is counted once
+per device-cache miss, and a disabled tracer leaves round outputs
+byte-identical."""
 import json
 import threading
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro import obs
 from repro.configs.base import FLConfig
-from repro.core import ota, packing
+from repro.core import ota, packing, wire
 from repro.fl import FLServer, StreamingFLServer
 
 
@@ -75,19 +79,37 @@ def test_enabled_restores_prior_state():
     assert not obs.is_enabled()
 
 
-def test_traced_decorator():
-    calls = []
+def test_spans_sit_on_the_profiler_clock(tmp_path):
+    """While enabled, each span is a profiler annotation of its name: it
+    lands in the ``.xplane.pb`` host plane, within 1 ms of where an
+    anchor annotation opened at a known ``perf_counter`` places it (the
+    benchmark harness's alignment of the tracer's events)."""
+    import time
 
-    @obs.traced("deco.fn")
-    def fn(x):
-        calls.append(x)
-        return x + 1
+    from jax.profiler import ProfileData
 
-    assert fn(1) == 2  # disabled: plain passthrough
+    names = ("probe.outer", "probe.inner")
     with obs.enabled() as t:
-        assert fn(2) == 3
-        assert t.summary()["deco.fn"]["count"] == 1
-    assert calls == [1, 2]
+        epoch = time.perf_counter_ns()  # enabled() has just reset the epoch
+        jax.profiler.start_trace(str(tmp_path))
+        t_pc0 = time.perf_counter_ns()
+        with jax.profiler.TraceAnnotation("probe.window"):
+            with obs.span(names[0]):
+                time.sleep(0.002)
+                with obs.span(names[1]):
+                    jnp.ones(3).block_until_ready()
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    host = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    host.setdefault(ev.name, float(ev.start_ns))
+    assert set(names) | {"probe.window"} <= set(host)
+    for e in t.events:
+        placed = host["probe.window"] + e.ts_us * 1e3 + epoch - t_pc0
+        assert abs(host[e.name] - placed) < 1e6, e.name
 
 
 def test_perfetto_export_roundtrips():
@@ -243,6 +265,11 @@ def _run_one_round(server_cls, *, enabled):
     return srv, log, names, snap
 
 
+CLIENT_SPANS = ("client.update", "client.setup", "client.batch",
+                "client.dispatch", "client.loss_read", "client.delta",
+                "uplink_encode")
+
+
 def test_servers_emit_same_span_names():
     """No deadline, full fill: the streaming engine's trace is the
     synchronous engine's trace — identical span name sets (and >= 7
@@ -254,6 +281,45 @@ def test_servers_emit_same_span_names():
     assert {"round", "plan", "client_train", "uplink_encode", "fold",
             "finalize", "optimizer", "broadcast_encode",
             "feedback"} <= sync_names
+    assert set(CLIENT_SPANS) <= sync_names
+
+
+@pytest.mark.parametrize("server_cls", [FLServer, StreamingFLServer])
+def test_client_spans_nest_and_count_local_steps(server_cls):
+    """Both round loops run each client's update under ``client_train``
+    with the same stage spans; the per-step spans number exactly the
+    ``fl.local_steps`` counter, local_steps x trained clients."""
+    steps = 2
+    with obs.enabled() as t:
+        obs.metrics.reset()
+        srv = server_cls(_cfg(local_steps=steps), shard_size=4)
+        srv.run_round(0)
+        evs = t.events
+    by = {n: [e for e in evs if e.name == n] for n in CLIENT_SPANS}
+    by["client_train"] = [e for e in evs if e.name == "client_train"]
+
+    def parent(e, name):
+        (p,) = [
+            p for p in by[name]
+            if p.ts_us <= e.ts_us
+            and e.ts_us + e.dur_us <= p.ts_us + p.dur_us + 1e-3
+        ]
+        assert e.depth == p.depth + 1, (e.name, name)
+
+    trained = len(by["client.update"])
+    assert trained == srv.cfg.clients_per_round
+    for e in by["client.update"]:
+        parent(e, "client_train")
+    for name in CLIENT_SPANS[1:]:
+        assert by[name], name
+        for e in by[name]:
+            parent(e, "client.update")
+    local_steps = obs.metrics.get("fl.local_steps")
+    assert local_steps == steps * trained
+    for name in ("client.batch", "client.dispatch", "client.loss_read"):
+        assert len(by[name]) == local_steps, name
+    for name in ("client.setup", "client.delta", "uplink_encode"):
+        assert len(by[name]) == trained, name
 
 
 def test_metrics_bytes_match_roundlog_bitwise():
@@ -283,3 +349,60 @@ def test_stream_round_log_publishes_stream_metrics():
     assert snap["counters"]["stream.on_time"] == log.n_on_time
     assert snap["counters"]["stream.lost"] == log.n_lost
     assert snap["gauges"]["stream.sim_seconds"] == log.sim_seconds
+
+
+# ---------------------------------------------------------------------------
+# arena upload and wire codec
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("storage", ["f32", "int8"])
+def test_arena_upload_counts_the_slab_once_per_live_count(storage):
+    """A device-cache miss uploads the whole capacity slab (and its int8
+    scales) and counts its bytes; a repeat query at the same live count
+    uploads nothing; an append misses again."""
+    from repro.retrieval.arena import ArenaStore
+    from repro.retrieval.engine import RetrievalEngine
+
+    rng = np.random.RandomState(0)
+    st = ArenaStore(128, storage=storage, capacity=256)
+    st.add_batch(rng.randn(40, 128).astype(np.float32))
+    data, scales = st.raw()
+    slab = data.nbytes + (0 if scales is None else scales.nbytes)
+    eng = RetrievalEngine(st, use_kernel=True)
+    queries = rng.randn(2, 128).astype(np.float32)
+
+    def uploaded():
+        get = obs.metrics.get
+        return get("retrieval.upload_bytes", 0.0), get("retrieval.uploads", 0.0)
+
+    obs.metrics.reset()
+    with obs.enabled() as t:
+        eng.topk(queries, 5)
+        assert uploaded() == (slab, 1)
+        eng.topk(queries, 5)
+        assert uploaded() == (slab, 1)
+        st.add_batch(rng.randn(3, 128).astype(np.float32))
+        eng.topk(queries, 5)
+        assert uploaded() == (2 * slab, 2)
+    ups = [e for e in t.events if e.name == "retrieval.upload"]
+    assert len(ups) == 2 and all(e.depth == 1 for e in ups)
+
+
+def test_encode_row_same_traced_or_not_and_records_nothing():
+    """The codec reads nothing from the device for telemetry: a row
+    encodes to the same bytes with tracing on and off, and no histogram
+    is recorded."""
+    key = jax.random.key(3)
+    row = jax.random.normal(key, (1024,), jnp.float32)
+    seed = ota.derive_sr_seed(key)
+    obs.metrics.reset()
+    for bits in (4, 8, 16):
+        with obs.disabled():
+            off = wire.encode_row(row, bits, seed, 1, block=256)
+        with obs.enabled():
+            on = wire.encode_row(row, bits, seed, 1, block=256)
+        np.testing.assert_array_equal(np.asarray(on.data), np.asarray(off.data))
+        np.testing.assert_array_equal(np.asarray(on.scale), np.asarray(off.scale))
+        assert (on.bits, on.qblock) == (off.bits, off.qblock)
+    assert obs.metrics.snapshot()["histograms"] == {}
