@@ -23,6 +23,7 @@ uplink a simulated arrival time for the streaming round loop
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -44,6 +45,26 @@ Pytree = Any
 # process-wide compiled-step cache: all clients at the same (arch, bits,
 # lr) share one XLA executable — compile once, reuse across the federation.
 _STEP_CACHE: Dict[Tuple[str, int, float], Tuple[Callable, Any]] = {}
+
+
+@functools.partial(jax.jit, static_argnames=("layout",))
+def client_delta(
+    new_params: Pytree, old_params: Pytree, layout: Optional[packing.Layout] = None
+) -> Any:
+    """The client's update ``new - old`` in f32, as one compiled program.
+
+    With ``layout`` the delta comes back flat-packed (``packing.pack``:
+    the (padded_size,) f32 row), else as the delta pytree. The same f32
+    subtract and concatenate the eager ops would run, so the result is
+    bitwise theirs; the program keys on the layout and the trees' shapes
+    only, never on the bits or the number of local steps.
+    """
+    delta = jax.tree.map(
+        lambda new, old: new.astype(jnp.float32) - old.astype(jnp.float32),
+        new_params,
+        old_params,
+    )
+    return delta if layout is None else packing.pack(delta, layout)
 
 
 # simulated uplink rate per device class (Mbit/s). ``DeviceSpec`` carries
@@ -150,7 +171,7 @@ class FLClient:
         quant_block: int = 0,
         channel_gain: Optional[float] = None,
         channel_habs: Optional[float] = None,
-    ) -> Tuple[Any, Dict[str, float]]:
+    ) -> Tuple[Any, Dict[str, Any]]:
         """Run local steps; return (delta, metrics).
 
         With ``layout`` alone, delta is the flat-packed (padded_size,) f32
@@ -169,11 +190,17 @@ class FLClient:
         state for the client (``core.channel``, DESIGN.md §12) — echoed
         into the returned metrics as uplink metadata, the per-round
         radio report that rides alongside the packed row.
+
+        The update never waits on the device: ``loss_first`` and
+        ``loss_last`` are 0-d device arrays, so the host enqueues step
+        s+1 (and the next client's work) while step s runs. The caller
+        reads them when it needs them (``FLServer._train_cohort`` reads
+        the whole cohort's at once).
         """
         # Host spans of the update's stages (DESIGN.md §14): ``client.batch``
-        # (host batch + transfers), ``client.dispatch`` (the jitted step's
-        # enqueue) and ``client.loss_read`` (the host blocked on the step)
-        # split each local step's wall time.
+        # (host batch + transfers) and ``client.dispatch`` (the jitted step's
+        # enqueue) split each local step's wall time; ``client.delta`` is the
+        # enqueue of the compiled delta (+ pack).
         with obs.span("client.update", user=self.user.user_id):
             jitted, opt = self._step_fn(bits, lr, fedprox_mu)
             with obs.span("client.setup"):
@@ -198,19 +225,10 @@ class FLClient:
                     batch = {k: jnp.asarray(v) for k, v in batch.items()}
                 with obs.span("client.dispatch"):
                     state, metrics = jitted(state, batch)
-                with obs.span("client.loss_read"):
-                    losses.append(float(metrics["loss"]))
+                losses.append(metrics["loss"])
             obs.metrics.inc("fl.local_steps", local_steps)
             with obs.span("client.delta"):
-                delta = jax.tree.map(
-                    lambda new, old: (
-                        new.astype(jnp.float32) - old.astype(jnp.float32)
-                    ),
-                    state["params"],
-                    global_params,
-                )
-                if layout is not None:
-                    delta = packing.pack(delta, layout)
+                delta = client_delta(state["params"], global_params, layout)
             if layout is not None and sr_seed is not None:
                 from repro.core import wire
 

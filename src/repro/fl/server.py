@@ -245,14 +245,21 @@ class FLServer:
         entirely (the server knows they cannot invert their channel this
         round) — and ``row_gains[j]`` is row j's effective receive gain,
         aligned with ``deltas`` (None when ideal).
+
+        The host blocks on the device only where it needs a value: once
+        for the channel's gains (fading only), and once after the loop for
+        every client's last loss, so the cohort's steps run while the
+        host prepares the next ones. Each such wait counts ``fl.train_syncs``.
         """
         deltas, weights, losses, active_ids = [], [], [], []
         row_gains: Optional[List[float]] = None
         gains_np = habs_np = None
         if chan_state is not None:
             row_gains = []
-            gains_np = np.asarray(jax.device_get(chan_state.gains))
-            habs_np = np.asarray(jax.device_get(chan_state.habs))
+            gains_np, habs_np = map(
+                np.asarray, jax.device_get((chan_state.gains, chan_state.habs))
+            )
+            obs.metrics.inc("fl.train_syncs")
         drop_rng = round_rng(self.cfg.seed, rnd)
         for pos, (d, i) in enumerate(zip(decisions, ids)):
             if gains_np is not None and gains_np[pos] <= 0.0:
@@ -292,6 +299,10 @@ class FLServer:
             weights.append(m["n_samples"] * contrib)
             losses.append(m["loss_last"])
             active_ids.append(i)
+        if losses:
+            with obs.span("client.loss_read"):
+                losses = [float(x) for x in jax.device_get(losses)]
+            obs.metrics.inc("fl.train_syncs")
         return deltas, weights, losses, active_ids, row_gains
 
     def _sample_round_channel(self, round_key, ids: List[int]):

@@ -6,21 +6,23 @@ host-time intervals — around the FL round pipeline (``fl/server.py``:
 ``plan -> channel_sample -> client_train -> uplink_encode -> fold ->
 finalize -> optimizer -> broadcast_encode -> feedback``), each client's
 local update inside ``client_train`` (``fl/client.py``: ``client.update
-> client.setup, client.batch, client.dispatch, client.loss_read,
-client.delta, uplink_encode``), the retrieval query path
-(``retrieval/engine.py``: ``retrieval.query > retrieval.upload``), and
-the serving engine's prefill/decode steps (``serve/engine.py``).
+> client.setup, client.batch, client.dispatch, client.delta,
+uplink_encode``; then one ``client.loss_read`` for the whole cohort),
+the retrieval query path (``retrieval/engine.py``: ``retrieval.query >
+retrieval.upload``), and the serving engine's prefill/decode steps
+(``serve/engine.py``).
 
 Spans measure *host* wall-clock. jax dispatch is asynchronous, so a span
 around a jitted call times its dispatch, not its device execution; a
 span ends on the device's clock only where its stage ends in a host
-sync: ``client.loss_read`` (the blocking loss read), ``plan`` (the top-k
-results' ``np.asarray``), and ``retrieval.upload``, which waits for its
-transfer while tracing is on (the query blocks on a result of that
-upload anyway). Device time is the profiler's to give: while enabled,
-every span also opens a ``jax.profiler.TraceAnnotation`` of its name, so
-under ``jax.profiler`` tracing the stages sit in the ``.xplane.pb`` host
-plane beside the device's programs, on the same clock.
+sync: ``client.loss_read`` (the round's one blocking read of the
+cohort's losses), ``plan`` (the top-k results' ``np.asarray``), and
+``retrieval.upload``, which waits for its transfer while tracing is on
+(the query blocks on a result of that upload anyway). Device time is
+the profiler's to give: while enabled, every span also opens a
+``jax.profiler.TraceAnnotation`` of its name, so under ``jax.profiler``
+tracing the stages sit in the ``.xplane.pb`` host plane beside the
+device's programs, on the same clock.
 
 Design constraints:
 

@@ -288,7 +288,9 @@ def test_servers_emit_same_span_names():
 def test_client_spans_nest_and_count_local_steps(server_cls):
     """Both round loops run each client's update under ``client_train``
     with the same stage spans; the per-step spans number exactly the
-    ``fl.local_steps`` counter, local_steps x trained clients."""
+    ``fl.local_steps`` counter, local_steps x trained clients, and the
+    round waits on the device once, in one ``client.loss_read`` under
+    ``client_train`` after every client's update."""
     steps = 2
     with obs.enabled() as t:
         obs.metrics.reset()
@@ -313,13 +315,17 @@ def test_client_spans_nest_and_count_local_steps(server_cls):
     for name in CLIENT_SPANS[1:]:
         assert by[name], name
         for e in by[name]:
-            parent(e, "client.update")
+            parent(e, "client_train" if name == "client.loss_read"
+                   else "client.update")
     local_steps = obs.metrics.get("fl.local_steps")
     assert local_steps == steps * trained
-    for name in ("client.batch", "client.dispatch", "client.loss_read"):
+    for name in ("client.batch", "client.dispatch"):
         assert len(by[name]) == local_steps, name
     for name in ("client.setup", "client.delta", "uplink_encode"):
         assert len(by[name]) == trained, name
+    (read,) = by["client.loss_read"]
+    last = max(e.ts_us + e.dur_us for e in by["client.update"])
+    assert read.ts_us >= last
 
 
 def test_metrics_bytes_match_roundlog_bitwise():
